@@ -31,7 +31,7 @@ HOUR = 3_600_000
 
 
 def _make(entities=2_000, rate=800, fail_p=0.0, seed=0) -> FeatureStore:
-    fs = FeatureStore("bench-mat", interpret=True)
+    fs = FeatureStore("bench-mat")
     src = SyntheticEventSource(
         "tx", seed=seed, num_entities=entities, events_per_bucket=rate
     )
@@ -234,7 +234,7 @@ class _Pr1KernelStore(OnlineStore):
         t.event_ts, t.creation_ts, t.values = merge_ops.route_and_merge(
             t.keys_lo, t.keys_hi, t.event_ts, t.creation_ts, t.values,
             plan.uids, plan.winner_ev, wfeats,
-            creation_ts, interpret=self.interpret,
+            creation_ts,
         )
         return {
             "engine": "kernel_pr1", "inserts": plan.inserts,

@@ -87,7 +87,7 @@ def bench_merge_engines(rows: int = 50_000, batches: int = 5) -> dict:
 
 
 def _store(entities: int, hours: int = 8) -> FeatureStore:
-    fs = FeatureStore("bench-online", interpret=True)
+    fs = FeatureStore("bench-online")
     src = SyntheticEventSource("tx", num_entities=entities, events_per_bucket=600)
     fs.register_source(src)
     fs.create_feature_set(

@@ -25,7 +25,7 @@ HOUR = 3_600_000
 
 
 def _store(hours: int, entities: int) -> FeatureStore:
-    fs = FeatureStore("bench", interpret=True)
+    fs = FeatureStore("bench")
     src = SyntheticEventSource(
         "tx", num_entities=entities, events_per_bucket=400
     )

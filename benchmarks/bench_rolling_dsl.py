@@ -88,26 +88,17 @@ def run(sizes=(2_000, 10_000, 50_000), n_aggs=6) -> dict:
         t_xla_warm = time.perf_counter() - t0
 
         # correctness cross-check naive vs optimized (both emit rows in
-        # (entity, ts) sorted order).  The XLA fallback's global fp32 prefix
-        # drifts ~1e-7 * running-total (catastrophic cancellation: ~0.9 abs
-        # at 50k rows of ~100-valued events) — the Pallas kernel re-zeroes
-        # its prefix per block and does NOT drift (tests/kernels assert
-        # tight tolerances); allow the fallback drift here.
+        # (entity, ts) sorted order).
         for a in aggs:
             np.testing.assert_allclose(
                 out_xla[a.output], naive[a.output], rtol=1e-2, atol=1.0
             )
 
         # analytic TPU-kernel cost for the shared plan (per distinct window):
-        # prefix matmul (H+B)^2·F MACs per block + gather one-hot, vs the
-        # UDF's O(N·W·A) reads.
-        feat = 2  # distinct source columns
+        # one (F, H+B) @ (H+B, B) window-mask matmul per block of B=256 rows
+        # at H=256, F padded to 8 sublanes, vs the UDF's O(N·W·A) reads.
         n_windows = len({a.window for a in aggs})
-        kernel_flops = (
-            n_windows
-            * (len(table) / 256)
-            * (512 * 512 * feat * 2 + 256 * 513 * feat * 2)
-        )
+        kernel_flops = n_windows * (len(table) / 256) * (8 * 512 * 256 * 2)
         naive_reads = sum(
             float(np.sum(np.minimum(np.arange(len(table)) + 1, 200)))  # ~avg span
             for _ in aggs

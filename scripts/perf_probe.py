@@ -5,12 +5,9 @@
         --shape train_4k --layers 5 --tag ds3_iter3_ep_boundary
 """
 
-import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
 import argparse
 import json
+import os
 import time
 from pathlib import Path
 
@@ -28,6 +25,9 @@ def main():
     ap.add_argument("--memory-pass", action="store_true",
                     help="also run the rolled µ-batched memory pass")
     args = ap.parse_args()
+    # 512 host devices for the production mesh; set before anything starts a
+    # jax backend, which fixes the device count
+    os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
     from repro.configs import get_config
     from repro.configs.shapes import SHAPES

@@ -16,7 +16,9 @@ gate everywhere Python runs, scoped to exactly the trees the workflow's
   rare unsplittable literal;
 * double quotes for string literals (``quote-style = "double"``), except
   strings whose body contains a double quote — ruff keeps single quotes
-  there to avoid escaping;
+  there to avoid escaping — and strings nested in an f-string's
+  replacement fields (``f"x:{d['k']}"``), which Python 3.12 (PEP 701)
+  tokenizes as STRING tokens between FSTRING_START and FSTRING_END;
 * no trailing whitespace.
 """
 
@@ -28,6 +30,10 @@ from .. import registry
 
 _MAX_LEN = 88
 _PREFIX_CHARS = "rbfuRBFU"
+# PEP 701 f-string token types (Python >= 3.12); older tokenizers emit a
+# whole f-string as one STRING token and never produce these
+_FSTRING_START = getattr(tokenize, "FSTRING_START", None)
+_FSTRING_END = getattr(tokenize, "FSTRING_END", None)
 
 
 @registry.rule(
@@ -59,8 +65,13 @@ def check(ctx, project):
                 "trailing whitespace",
                 col=len(line.rstrip()),
             )
+    fstring_depth = 0
     for tok in ctx.tokens:
-        if tok.type != tokenize.STRING:
+        if tok.type == _FSTRING_START:
+            fstring_depth += 1
+        elif tok.type == _FSTRING_END:
+            fstring_depth -= 1
+        if tok.type != tokenize.STRING or fstring_depth:
             continue
         body = tok.string.lstrip(_PREFIX_CHARS)
         if body.startswith("'"):

@@ -121,6 +121,17 @@ __all__ = [
 PROTO_VERSION = 1
 _BANNER = "REPLICA_DAEMON_LISTENING"
 _RECV_CHUNK = 1 << 16
+# a replica daemon is a host process: it never takes an accelerator, so the
+# device-resident kernel engine is not among its engines
+_HOST_ENGINES = ("vector", "loop")
+
+
+def _require_host_engine(merge_engine: str) -> None:
+    if merge_engine not in _HOST_ENGINES:
+        raise ValueError(
+            f"a replica daemon is a host process; engine must be one of "
+            f"{_HOST_ENGINES}, got {merge_engine!r}"
+        )
 
 
 # -- schema transfer ----------------------------------------------------------
@@ -189,6 +200,7 @@ class ReplicaDaemon:
         initial_capacity: int = 256,
         offline_shards: int = 4,
     ) -> None:
+        _require_host_engine(merge_engine)
         self.region = region
         self.merge_engine = merge_engine
         self.online = OnlineStore(
@@ -492,8 +504,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=0, help="0 = ephemeral")
     ap.add_argument("--region", default="replica")
-    ap.add_argument("--engine", default="vector",
-                    choices=("vector", "kernel", "loop"))
+    ap.add_argument("--engine", default="vector", choices=_HOST_ENGINES)
     ap.add_argument("--no-offline", action="store_true")
     ap.add_argument("--partitions", type=int, default=16)
     ap.add_argument("--capacity", type=int, default=256)
@@ -612,10 +623,13 @@ def spawn_replica_daemon(
     """Launch ``python -m repro.core.daemon`` as a child process and block
     until it announces its ephemeral port on stdout."""
     src_root = os.path.dirname(os.path.dirname(os.path.dirname(__file__)))
+    _require_host_engine(merge_engine)
     env = dict(os.environ)
     env["PYTHONPATH"] = src_root + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
     )
+    # the parent may hold the accelerator; the child must never ask for it
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [
         sys.executable,
         "-m",

@@ -10,7 +10,7 @@ based on join results"):
     ONCE and shared by every aggregation over the same window length;
   * aggregations over the same source column share the loaded column;
   * sum-family aggregations lower to the Pallas rolling-sum kernel
-    (kernels/rolling_agg) — O(N) prefix work instead of O(N·W);
+    (kernels/rolling_agg) — one masked MXU matmul per row block;
   * count is closed-form from the shared window indices (zero data reads).
 
 ``UDFTransform`` is the black-box path: an arbitrary
@@ -63,7 +63,6 @@ class DslTransform(TransformProtocol):
         timestamp_col: str,
         aggs: Sequence[RollingAgg],
         *,
-        interpret: bool = True,
         use_kernel: bool = True,
     ) -> None:
         if not aggs:
@@ -73,7 +72,6 @@ class DslTransform(TransformProtocol):
         )
         self.timestamp_col = timestamp_col
         self.aggs = tuple(aggs)
-        self.interpret = interpret
         self.use_kernel = use_kernel
         outs = [a.output for a in self.aggs]
         if len(set(outs)) != len(outs):
@@ -128,8 +126,8 @@ class DslTransform(TransformProtocol):
             sums = np.asarray(
                 rolling_ops.rolling_agg(
                     jnp.asarray(mat), starts_by_window[window], "sum",
-                    interpret=self.interpret,
                     backend="pallas" if self.use_kernel else "xla",
+                    monitor=context.get("monitor"),
                 )
             )
             counts = np.arange(n) + 1 - starts_by_window[window]
@@ -151,7 +149,7 @@ class DslTransform(TransformProtocol):
                 vals = sorted_df[a.source_col].astype(np.float32)[:, None]
                 out_cols[a.output] = np.asarray(
                     rolling_ops.rolling_agg(
-                        jnp.asarray(vals), starts, a.agg, interpret=self.interpret
+                        jnp.asarray(vals), starts, a.agg
                     )
                 )[:, 0].astype(np.float32)
 

@@ -52,7 +52,6 @@ class FeatureStore:
         clock: Optional[Callable[[], int]] = None,
         offline_shards: int = 4,
         online_partitions: int = 16,
-        interpret: bool = True,
         merge_engine: str = "vector",
         serving: Optional[ServingConfig] = None,
     ) -> None:
@@ -65,7 +64,6 @@ class FeatureStore:
         )
         self.online = OnlineStore(
             num_partitions=online_partitions,
-            interpret=interpret,
             merge_engine=merge_engine,
         )
         self.scheduler = Scheduler()
@@ -73,7 +71,11 @@ class FeatureStore:
         self.lineage = LineageGraph()
         self.faults = FaultInjector()
         self.materializer = Materializer(
-            self.offline, self.online, clock=self.clock, faults=self.faults
+            self.offline,
+            self.online,
+            clock=self.clock,
+            faults=self.faults,
+            monitor=self.monitor,
         )
         if topology is None:
             topology = GeoTopology(regions={region: Region(region)})
@@ -94,7 +96,6 @@ class FeatureStore:
         # online merges cross-region (core/replication.py)
         self.replicator = None
         self._sources: dict[str, SourceProtocol] = {}
-        self.interpret = interpret
 
         from repro.runtime.supervisor import Supervisor  # avoid cycle
 
@@ -231,8 +232,8 @@ class FeatureStore:
             spine,
             specs,
             spine_ts_col=spine_ts_col,
-            interpret=self.interpret,
             use_kernel=use_kernel,
+            monitor=self.monitor,
         )
 
     def get_online_features(

@@ -17,6 +17,7 @@ import dataclasses
 from typing import Callable, Optional
 
 from repro.core.assets import FeatureSetSpec
+from repro.core.monitoring import HealthMonitor
 from repro.core.offline_store import OfflineStore
 from repro.core.online_store import OnlineStore
 from repro.core.scheduler import MaterializationJob
@@ -79,6 +80,7 @@ class Materializer:
         clock: Callable[[], int],
         faults: Optional[FaultInjector] = None,
         merge_engine: Optional[str] = None,
+        monitor: Optional[HealthMonitor] = None,
     ) -> None:
         self.offline = offline
         self.online = online
@@ -87,6 +89,9 @@ class Materializer:
         # None -> each store's own default; "loop"/"vector"/"kernel" forces
         # one write path end-to-end (benchmarks flip old-style vs engine here)
         self.merge_engine = merge_engine
+        # handed to transforms through Algorithm 1's context, so a DSL plan
+        # that leaves the kernel path says so in the store's monitoring
+        self.monitor = monitor
         self.outcomes: list[MaterializationOutcome] = []
 
     def run_job(
@@ -99,7 +104,9 @@ class Materializer:
         merge order — offline first, then online — is fixed, which is one of
         the §4.5.4 reasons the stores are only EVENTUALLY consistent."""
         self.faults.check("before_compute")
-        frame = compute_feature_window(spec, source, job.window)
+        frame = compute_feature_window(
+            spec, source, job.window, {"monitor": self.monitor}
+        )
         self.faults.check("after_compute")
 
         creation_ts = int(self.clock())

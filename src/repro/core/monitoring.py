@@ -159,6 +159,22 @@ class HealthMonitor:
         if ms is not None:
             self.system.set_gauge(f"staleness_ms/{feature_set}:v{version}", float(ms))
 
+    def record_kernel_fallback(self, kernel: str) -> None:
+        """A call that asked for a Pallas kernel ran its XLA or reference
+        formulation instead (a rolling span deeper than the kernel's VMEM
+        history, a PIT span outside its int32 domain).  Counted per kernel
+        so a run can see, and a smoke test can require, that the device
+        path ran."""
+        self.system.inc(f"kernel_fallbacks/{kernel}")
+
+    def kernel_fallbacks(self) -> dict[str, float]:
+        """Fallback counts per kernel since the monitor was created."""
+        return {
+            k.split("/", 1)[1]: v
+            for k, v in self.system.counters.items()
+            if k.startswith("kernel_fallbacks/")
+        }
+
     def record_lookup_latency(self, us: float) -> None:
         self.system.observe("online_lookup_us", us)
 

@@ -90,7 +90,6 @@ class MultiHomeGeoStore:
         delivery_policy: Optional[DeliveryPolicy] = None,
         offline_shards: int = 4,
         online_partitions: int = 16,
-        interpret: bool = True,
         merge_engine: str = "vector",
         clock: Optional[Callable[[], int]] = None,
     ) -> None:
@@ -117,7 +116,6 @@ class MultiHomeGeoStore:
         self._store_cfg = {
             "online_partitions": online_partitions,
             "offline_shards": offline_shards,
-            "interpret": interpret,
             "merge_engine": merge_engine,
         }
         self._log_capacity = log_capacity
@@ -152,7 +150,6 @@ class MultiHomeGeoStore:
         cfg = self._store_cfg
         online = OnlineStore(
             num_partitions=cfg["online_partitions"],
-            interpret=cfg["interpret"],
             merge_engine=cfg["merge_engine"],
         )
         offline = OfflineStore(

@@ -224,14 +224,12 @@ class OnlineStore:
         num_partitions: int = 16,
         initial_capacity: int = 256,
         *,
-        interpret: bool = True,
         merge_engine: str = "vector",
     ):
         if merge_engine not in ("vector", "kernel", "loop"):
             raise ValueError(f"unknown merge engine {merge_engine!r}")
         self.num_partitions = num_partitions
         self.initial_capacity = initial_capacity
-        self.interpret = interpret
         self.merge_engine = merge_engine
         self._tables: dict[tuple[str, int], _PartitionedTable] = {}
         self._specs: dict[tuple[str, int], FeatureSetSpec] = {}
@@ -827,7 +825,6 @@ class OnlineStore:
                 lookup_ops.lookup(
                     dev.keys_lo, dev.keys_hi,
                     jnp.asarray(q_lo), jnp.asarray(q_hi),
-                    interpret=self.interpret,
                 )
             )
             self.transfers["h2d_bytes"] += _nbytes(q_lo, q_hi)

@@ -20,6 +20,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -59,10 +60,14 @@ def pit_join_feature_set(
     spec: FeatureSetSpec,
     history: Table,
     *,
-    interpret: bool = True,
     use_kernel: bool = True,
+    monitor=None,
 ) -> PitResult:
-    """Join one feature set's history onto the spine, point-in-time correct."""
+    """Join one feature set's history onto the spine, point-in-time correct.
+
+    A span too wide for the kernel's int32 domain takes the jnp oracle and,
+    when ``monitor`` (a ``HealthMonitor``) is given, is counted there as a
+    kernel fallback."""
     b = len(spine_ts)
     spine_ts = np.asarray(spine_ts, dtype=np.int64)
     ids = encode_keys(spine_keys)
@@ -98,17 +103,21 @@ def pit_join_feature_set(
             jnp.asarray(np.maximum(q_ts - lo_ts, -1).astype(np.int32)),
             jnp.asarray(q_lo.astype(np.int32)),
             jnp.asarray(q_hi.astype(np.int32)),
-            interpret=interpret,
         )
         idx, valid = np.asarray(idx), np.asarray(valid)
     else:
-        idx, valid = pit_ref.pit_search_ref(
-            jnp.asarray(table_ev),
-            jnp.asarray(q_ts),
-            jnp.asarray(q_lo),
-            jnp.asarray(q_hi),
-        )
-        idx, valid = np.asarray(idx), np.asarray(valid)
+        if use_kernel and monitor is not None:
+            monitor.record_kernel_fallback("pit_join")
+        # int64 epoch-ms compares: under jax's default 32-bit mode
+        # jnp.asarray would wrap timestamps past 2**31
+        with jax.enable_x64(True):
+            idx, valid = pit_ref.pit_search_ref(
+                jnp.asarray(table_ev),
+                jnp.asarray(q_ts),
+                jnp.asarray(q_lo),
+                jnp.asarray(q_hi),
+            )
+            idx, valid = np.asarray(idx), np.asarray(valid)
     # Queries whose ts0 - delay predates the rebase floor can match nothing.
     valid = valid & has_entity
 
@@ -127,8 +136,8 @@ def get_offline_features(
     specs: Sequence[FeatureSetSpec],
     *,
     spine_ts_col: str = "ts",
-    interpret: bool = True,
     use_kernel: bool = True,
+    monitor=None,
 ) -> Table:
     """Spine join across many feature sets (the training-data path).
 
@@ -145,8 +154,8 @@ def get_offline_features(
             spine[spine_ts_col],
             spec,
             history,
-            interpret=interpret,
             use_kernel=use_kernel,
+            monitor=monitor,
         )
         prefix = f"{spec.name}:v{spec.version}"
         for fname, vals in res.values.items():

@@ -1557,7 +1557,6 @@ class GeoReplicator:
         store = OnlineStore(
             home.num_partitions,
             home.initial_capacity,
-            interpret=home.interpret,
             merge_engine=home.merge_engine,
         )
         home_off = self.offline_stores.get(self.home_region)
@@ -1770,7 +1769,6 @@ class GeoFeatureStore:
         store = OnlineStore(
             num_partitions=home.num_partitions,
             initial_capacity=home.initial_capacity,
-            interpret=home.interpret,
             merge_engine=home.merge_engine,
         )
         offline = OfflineStore(

@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mode import interpret_mode
+
 __all__ = ["flash_attention_kernel_call"]
 
 NEG_INF = -1e30
@@ -98,8 +100,10 @@ def flash_attention_kernel_call(
     block_q: int = 512,
     block_k: int = 512,
     causal: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
+    if interpret is None:
+        interpret = interpret_mode()
     n, s, d = q.shape
     t = k.shape[1]
     if s % block_q or t % block_k:

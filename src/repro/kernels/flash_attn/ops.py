@@ -25,9 +25,7 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-@functools.partial(
-    jax.jit, static_argnames=("block_q", "block_k", "causal", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("block_q", "block_k", "causal"))
 def flash_attention(
     q: jnp.ndarray,   # (B, S, H, D)
     k: jnp.ndarray,   # (B, T, KV, D)
@@ -36,7 +34,6 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 512,
     causal: bool = True,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     b, s, h, d = q.shape
     t, kv = k.shape[1], k.shape[2]
@@ -64,7 +61,7 @@ def flash_attention(
             raise NotImplementedError("non-causal padding path unused")
 
     out = flash_attention_kernel_call(
-        qf, kf, vf, block_q=bq, block_k=bk, causal=causal, interpret=interpret
+        qf, kf, vf, block_q=bq, block_k=bk, causal=causal
     )
     out = out[:, :s].reshape(b, h, s, d)
     return jnp.moveaxis(out, 1, 2)  # (B, S, H, D)

@@ -118,7 +118,7 @@ def route_flat(
     return tuple(out)
 
 
-@functools.partial(jax.jit, static_argnames=("slot_block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("slot_block",))
 def lookup(
     keys_lo: jnp.ndarray,
     keys_hi: jnp.ndarray,
@@ -126,7 +126,6 @@ def lookup(
     q_hi: jnp.ndarray,
     *,
     slot_block: int = 1024,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Pre-routed lookup.  keys (P, C), queries (P, Q) -> slots (P, Q)."""
     p, c = keys_lo.shape
@@ -145,10 +144,12 @@ def lookup(
         padq = jnp.full((p, q_pad - q), -2, jnp.int32)
         q_lo = jnp.concatenate([q_lo, padq], axis=1)
         q_hi = jnp.concatenate([q_hi, padq], axis=1)
+    qb = 256 if q_pad % 256 == 0 else _LANE
     out = lookup_kernel_call(
-        keys_lo, keys_hi, q_lo, q_hi, slot_block=sb, interpret=interpret
+        keys_lo, keys_hi, q_lo[..., None], q_hi[..., None],
+        slot_block=sb, q_block=qb,
     )
-    return out[:, :q]
+    return out[:, :q, 0]
 
 
 def route_queries(
@@ -202,8 +203,6 @@ def route_and_lookup(
     keys_hi: np.ndarray,
     values: np.ndarray,
     ids: np.ndarray,
-    *,
-    interpret: bool = True,
 ) -> tuple[np.ndarray, np.ndarray]:
     """Flat query path: ids (B,) int64 against table (P, C) + values (P, C, D).
 
@@ -222,7 +221,6 @@ def route_and_lookup(
             jnp.asarray(keys_hi),
             jnp.asarray(q_lo),
             jnp.asarray(q_hi),
-            interpret=interpret,
         )
     )
     got = slots[part, slot_in_part]

@@ -16,9 +16,7 @@ Two device-side merge variants share the partitioned plane layout:
     route a flat per-id-winner batch to hash partitions, pad to lane shapes,
     split int64 ids/timestamps into int32 planes, and let the Pallas kernel
     broadcast-match every slot block (O(C·Q) scan).  Retained as the parity
-    reference and for callers without a host-side slot index; its table
-    planes are aliased input->output so it also updates in place when jitted
-    with donation.
+    reference and for callers without a host-side slot index.
 
 ``gather_slot_ts`` is the read half of the resident protocol: fetch the
 current (event_ts, creation_ts) planes at resolved coords so the host merge
@@ -50,6 +48,7 @@ __all__ = [
 ]
 
 _LANE = 128
+_SUBLANE = 8
 
 
 def _round_up(x: int, m: int) -> int:
@@ -140,7 +139,7 @@ def gather_slot_ts(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("slot_block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("slot_block",))
 def merge(
     keys_lo: jnp.ndarray,
     keys_hi: jnp.ndarray,
@@ -157,61 +156,47 @@ def merge(
     creation_planes: jnp.ndarray,
     *,
     slot_block: int = 512,
-    interpret: bool = True,
 ) -> tuple[jnp.ndarray, ...]:
     """Pre-routed merge.  Table planes (P, C) (+ values (P, C, D)), routed
     queries (P, Q) (+ values (P, Q, D)) -> updated ev/cr planes + values.
-    Handles slot-block/lane padding; at most one query per key."""
+    Handles slot-block/lane padding and the kernel's transposed value
+    layout; at most one query per key."""
     p, c = keys_lo.shape
     d = values.shape[-1]
     c_pad = _round_up(c, min(slot_block, _round_up(c, _LANE)))
     sb = min(slot_block, c_pad)
     c_pad = _round_up(c_pad, sb)
-    if c_pad != c:
-        padk = jnp.full((p, c_pad - c), -1, jnp.int32)
-        pad0 = jnp.zeros((p, c_pad - c), jnp.int32)
-        keys_lo = jnp.concatenate([keys_lo, padk], axis=1)
-        keys_hi = jnp.concatenate([keys_hi, padk], axis=1)
-        ev_lo = jnp.concatenate([ev_lo, pad0], axis=1)
-        ev_hi = jnp.concatenate([ev_hi, pad0], axis=1)
-        cr_lo = jnp.concatenate([cr_lo, pad0], axis=1)
-        cr_hi = jnp.concatenate([cr_hi, pad0], axis=1)
-        values = jnp.concatenate(
-            [values, jnp.zeros((p, c_pad - c, d), jnp.float32)], axis=1
-        )
     q = q_lo.shape[1]
     q_pad = _round_up(q, _LANE)
-    if q_pad != q:
-        # (-2, -2) padding: matches neither live keys nor the empty sentinel
-        padq = jnp.full((p, q_pad - q), -2, jnp.int32)
-        pad0q = jnp.zeros((p, q_pad - q), jnp.int32)
-        q_lo = jnp.concatenate([q_lo, padq], axis=1)
-        q_hi = jnp.concatenate([q_hi, padq], axis=1)
-        q_ev_lo = jnp.concatenate([q_ev_lo, pad0q], axis=1)
-        q_ev_hi = jnp.concatenate([q_ev_hi, pad0q], axis=1)
-        q_values = jnp.concatenate(
-            [q_values, jnp.zeros((p, q_pad - q, d), jnp.float32)], axis=1
-        )
-    d_pad = _round_up(d, _LANE) if not interpret else d
-    if d_pad != d:
-        values = jnp.concatenate(
-            [values, jnp.zeros((p, c_pad, d_pad - d), jnp.float32)], axis=2
-        )
-        q_values = jnp.concatenate(
-            [q_values, jnp.zeros((p, q_pad, d_pad - d), jnp.float32)], axis=2
-        )
+    d_pad = _round_up(d, _SUBLANE)
+
+    def pad2(x, n, fill):
+        return jnp.pad(x, ((0, 0), (0, n - x.shape[1])), constant_values=fill)
+
+    def values_t(x, n):  # (P, N, D) -> (P, D_pad, N_pad), zero-padded
+        x = jnp.swapaxes(x, 1, 2)
+        return jnp.pad(x, ((0, 0), (0, d_pad - d), (0, n - x.shape[2])))
+
+    # table pads are empty slots (keys -1); query pads carry (-2, -2), which
+    # matches neither live keys nor the empty sentinel
     out = merge_kernel_call(
-        keys_lo, keys_hi, ev_lo, ev_hi, cr_lo, cr_hi, values,
-        q_lo, q_hi, q_ev_lo, q_ev_hi, q_values, creation_planes,
-        slot_block=sb, interpret=interpret,
+        pad2(keys_lo, c_pad, -1), pad2(keys_hi, c_pad, -1),
+        pad2(ev_lo, c_pad, 0), pad2(ev_hi, c_pad, 0),
+        pad2(cr_lo, c_pad, 0), pad2(cr_hi, c_pad, 0),
+        values_t(values, c_pad),
+        pad2(q_lo, q_pad, -2)[..., None], pad2(q_hi, q_pad, -2)[..., None],
+        pad2(q_ev_lo, q_pad, 0)[..., None], pad2(q_ev_hi, q_pad, 0)[..., None],
+        values_t(q_values, q_pad),
+        creation_planes,
+        slot_block=sb,
     )
-    ev_lo_u, ev_hi_u, cr_lo_u, cr_hi_u, vals_u = out
+    ev_lo_u, ev_hi_u, cr_lo_u, cr_hi_u, vals_t = out
     return (
         ev_lo_u[:, :c],
         ev_hi_u[:, :c],
         cr_lo_u[:, :c],
         cr_hi_u[:, :c],
-        vals_u[:, :c, :d],
+        jnp.swapaxes(vals_t[:, :d, :c], 1, 2),
     )
 
 
@@ -225,8 +210,6 @@ def route_and_merge(
     ev: np.ndarray,
     vals: np.ndarray,
     batch_creation_ts: int,
-    *,
-    interpret: bool = True,
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Flat merge path: winner records ids (B,) int64 (UNIQUE), ev (B,) int64,
     vals (B, D) f32 against table planes (P, C) + int64 ts + values (P, C, D).
@@ -261,7 +244,6 @@ def route_and_merge(
         jnp.asarray(q_lo), jnp.asarray(q_hi),
         jnp.asarray(q_ev_lo), jnp.asarray(q_ev_hi),
         jnp.asarray(q_vals), jnp.asarray(cr_planes),
-        interpret=interpret,
     )
     ev_lo_u, ev_hi_u, cr_lo_u, cr_hi_u, vals_u = (np.asarray(o) for o in out)
     return (

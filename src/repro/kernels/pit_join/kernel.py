@@ -13,8 +13,11 @@ We trade O(log M) latency-bound probes for O(M/lanes) bandwidth-bound
 compares, the right trade on a machine with 128-wide lanes and sequential
 grids (same reasoning that makes flash-attention stream K/V tiles).
 
-Grid: (num_query_blocks, num_table_blocks), table minor (sequential), with an
-int32 count accumulator in VMEM scratch per query block.
+Grid: (num_query_blocks, num_table_blocks), table minor (sequential).  Each
+table block is ``rows`` lane-dense rows of 128 timestamps; the kernel compares
+one (1, 128) row at a time against the (Bq, 1) query columns and adds the
+hits into a (Bq, 128) int32 accumulator in VMEM scratch, which is reduced
+across lanes once, after the last table block.
 """
 
 from __future__ import annotations
@@ -26,39 +29,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mode import interpret_mode
+
 __all__ = ["pit_search_kernel_call"]
 
 _LANE = 128
 
 
-def _pit_kernel(qts_ref, qlo_ref, qhi_ref, tab_ref, out_ref, acc_ref, *, rows: int):
+def _pit_kernel(qts_ref, qlo_ref, qhi_ref, tab_ref, out_ref, acc_ref):
     tb = pl.program_id(1)
     n_tb = pl.num_programs(1)
+    rows = tab_ref.shape[0]
 
     @pl.when(tb == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    tab = tab_ref[...]                                   # (R, 128) int32 ts
-    qts = qts_ref[...]                                   # (Bq, 1)
+    qts = qts_ref[...]  # (Bq, 1)
     qlo = qlo_ref[...]
     qhi = qhi_ref[...]
-
-    base = tb * rows * _LANE
-    r_i = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANE), 0)
-    c_i = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANE), 1)
-    gidx = base + r_i * _LANE + c_i                      # global row index
-
-    pred = (
-        (gidx[None, :, :] >= qlo[:, :, None])
-        & (gidx[None, :, :] < qhi[:, :, None])
-        & (tab[None, :, :] <= qts[:, :, None])
-    )
-    acc_ref[...] += pred.sum(axis=(1, 2), dtype=jnp.int32)[:, None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANE), 1)
+    acc = acc_ref[...]  # (Bq, 128)
+    for r in range(rows):
+        gidx = (tb * rows + r) * _LANE + lane  # (1, 128) global row index
+        pred = (gidx >= qlo) & (gidx < qhi) & (tab_ref[pl.ds(r, 1), :] <= qts)
+        acc = acc + pred.astype(jnp.int32)
+    acc_ref[...] = acc
 
     @pl.when(tb == n_tb - 1)
     def _write():
-        out_ref[...] = acc_ref[...]
+        out_ref[...] = acc.sum(axis=1, keepdims=True)
 
 
 @functools.partial(
@@ -72,13 +72,15 @@ def pit_search_kernel_call(
     *,
     q_block: int = 512,
     table_rows_per_block: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Counting search.  table_ts2d: (Mr, 128) int32, row-major flattening of
     the padded table (padding rows carry ts = INT32_MAX and are excluded by
     q_hi anyway).  q_*: (B, 1) int32 with B % q_block == 0.  Returns (B, 1)
     int32 counts; caller derives idx = lo + count - 1, valid = count > 0.
     """
+    if interpret is None:
+        interpret = interpret_mode()
     mr, lane = table_ts2d.shape
     if lane != _LANE:
         raise ValueError(f"table must be (rows, {_LANE})")
@@ -86,9 +88,8 @@ def pit_search_kernel_call(
     if b % q_block or mr % table_rows_per_block:
         raise ValueError("shapes must be pre-padded by ops.py")
     grid = (b // q_block, mr // table_rows_per_block)
-    kernel = functools.partial(_pit_kernel, rows=table_rows_per_block)
     return pl.pallas_call(
-        kernel,
+        _pit_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((q_block, 1), lambda qb, tb: (qb, 0)),
@@ -98,6 +99,6 @@ def pit_search_kernel_call(
         ],
         out_specs=pl.BlockSpec((q_block, 1), lambda qb, tb: (qb, 0)),
         out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((q_block, 1), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((q_block, _LANE), jnp.int32)],
         interpret=interpret,
     )(q_ts, q_lo, q_hi, table_ts2d)

@@ -27,9 +27,7 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-@functools.partial(
-    jax.jit, static_argnames=("q_block", "table_rows_per_block", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("q_block", "table_rows_per_block"))
 def pit_search(
     table_ts: jnp.ndarray,
     q_ts: jnp.ndarray,
@@ -38,7 +36,6 @@ def pit_search(
     *,
     q_block: int = 512,
     table_rows_per_block: int = 8,
-    interpret: bool = True,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """table_ts (M,) int32 sorted within [lo,hi) segments; q_* (B,) int32.
 
@@ -66,7 +63,6 @@ def pit_search(
         pad_q(q_hi, 0),  # padded queries have hi=0 => empty range => count 0
         q_block=q_block,
         table_rows_per_block=table_rows_per_block,
-        interpret=interpret,
     )[:b, 0]
     idx = (q_lo + counts - 1).astype(jnp.int32)
     return idx, counts > 0

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: rolling-window sum via MXU prefix + one-hot gather.
+"""Pallas TPU kernel: rolling-window sum as one masked MXU matmul per block.
 
 TPU adaptation of the paper's §3.1.6 DSL-optimized rolling aggregation.  A
 Spark implementation shuffles rows into windows; on TPU we exploit two
@@ -6,22 +6,25 @@ hardware facts instead:
 
   1. The Pallas grid is *sequential*, so a VMEM scratch buffer can carry the
      trailing ``hist`` rows across row-blocks (flash-attention-style carry).
-  2. Prefix sums and gathers both lower to MXU matmuls: the inclusive prefix
-     is ``L @ ext`` with a lower-triangular ones matrix, and the per-row
-     window start gather is ``one_hot(rel_idx) @ P``.
+  2. A windowed sum is a matmul against a 0/1 window mask, which the MXU
+     does at full rate.
 
-For a block of B rows with window spans bounded by H rows, the window sum is
+Layout: rows on lanes, features on sublanes.  A block holds B rows of the
+(F, N) transposed value matrix (F padded to 8 sublanes by ops.py, so a
+narrow feature set costs 8 rows of padding, not 128 lanes) and the matching
+(1, B) slice of window starts.  With ``ext`` = the carried H history columns
+followed by the block's B columns, row j of the block sums
 
-    out[i] = P[i+1] - P[starts[i]]          (exclusive prefix P over hist+cur)
+    out[:, j] = sum(ext[:, k] for k in [rel[j], H + j]),
+    rel[j] = starts[j] - (b*B - H)        (its window start in ext coords)
 
-and both terms only need the *local* prefix over the (H + B)-row extended
-block — the contribution of everything before the history window cancels in
-the difference, so no global carry is required.
+i.e. ``out = ext @ W`` with W[k, j] = rel[j] <= k <= H + j — one (F, H+B) @
+(H+B, B) matmul.  Every output is a direct sum of its window (no prefix
+difference, so no cancellation across long columns).
 
-Grid: 1-D over row blocks.  VMEM working set per step:
-  ext (H+B, F) f32 + L (H+B, H+B) f32 + one-hot (B, H+B+1) f32
-e.g. H=B=256, F=128: 0.26 MB + 1.0 MB + 0.5 MB — comfortably in 16 MB VMEM,
-with MXU-aligned shapes (multiples of (8, 128) after ops.py padding).
+Grid: 1-D over row blocks.  VMEM working set per step is dominated by the
+(H+B, B) mask and its iota/compare temporaries; ``ops.max_hist`` derives the
+deepest history that fits ``VMEM_LIMIT_BYTES`` from that working set.
 """
 
 from __future__ import annotations
@@ -33,78 +36,70 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["rolling_sum_kernel_call"]
+from repro.kernels.mode import interpret_mode
+
+__all__ = ["VMEM_LIMIT_BYTES", "rolling_sum_kernel_call"]
+
+#: scoped VMEM the kernel asks the compiler for (v5e has 128 MiB per core;
+#: the compiler's default scope is 16 MiB)
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 
 
-def _rolling_sum_kernel(starts_ref, vals_ref, out_ref, hist_ref, *, hist: int):
+def _rolling_sum_kernel(starts_ref, vals_ref, out_ref, hist_ref):
     b = pl.program_id(0)
-    blk, feat = vals_ref.shape
+    blk = vals_ref.shape[1]
+    hist = hist_ref.shape[1]
 
     @pl.when(b == 0)
     def _init():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    cur = vals_ref[...].astype(jnp.float32)            # (B, F)
-    ext = jnp.concatenate([hist_ref[...], cur], axis=0)  # (H+B, F)
-    m = hist + blk
+    ext = jnp.concatenate([hist_ref[...], vals_ref[...]], axis=1)  # (F, H+B)
+    rel = starts_ref[...] - (b * blk - hist)  # (1, B)
+    k = jax.lax.broadcasted_iota(jnp.int32, (hist + blk, blk), 0)
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1) + hist
+    window = ((k >= rel) & (k <= last)).astype(jnp.float32)  # (H+B, B)
+    out_ref[...] = jax.lax.dot(ext, window, precision=jax.lax.Precision.HIGHEST)
 
-    # Inclusive prefix via lower-triangular MXU matmul: P_inc[k] = sum ext[:k+1].
-    row = jax.lax.broadcasted_iota(jnp.int32, (m, m), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (m, m), 1)
-    lower = (col <= row).astype(jnp.float32)           # (H+B, H+B)
-    p_inc = jax.lax.dot(lower, ext, precision=jax.lax.Precision.HIGHEST)
-    # Exclusive prefix P, shape (H+B+1, F): P[0] = 0, P[k] = sum ext[:k].
-    p_exc = jnp.concatenate([jnp.zeros((1, feat), jnp.float32), p_inc], axis=0)
-
-    # Window end term: P[i+1] in extended coordinates = P_exc[H + j + 1].
-    ends = p_exc[hist + 1 : hist + blk + 1, :]         # (B, F), static slice
-
-    # Window start term: gather P_exc at rel = starts - (b*B - H), via one-hot
-    # matmul (the TPU-native dynamic gather).
-    starts = starts_ref[...].reshape(blk)              # (B,) int32
-    rel = starts - b * blk + hist                      # in [0, H+B)
-    onehot = (
-        rel[:, None]
-        == jax.lax.broadcasted_iota(jnp.int32, (blk, m + 1), 1)
-    ).astype(jnp.float32)                              # (B, H+B+1)
-    gathered = jax.lax.dot(onehot, p_exc, precision=jax.lax.Precision.HIGHEST)
-
-    out_ref[...] = ends - gathered
-
-    # Carry the trailing H rows of raw values into the next block.
-    hist_ref[...] = ext[blk : blk + hist, :]
+    # carry the trailing H columns of raw values into the next block
+    hist_ref[...] = ext[:, blk:]
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "hist", "interpret"))
 def rolling_sum_kernel_call(
-    values: jnp.ndarray,
+    values_t: jnp.ndarray,
     starts: jnp.ndarray,
     *,
     block_rows: int = 256,
     hist: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """values: (N, F) with N % block_rows == 0 and window spans <= hist.
+    """values_t: (F, N) float32, starts: (1, N) int32, window spans <= hist.
 
-    ops.py is responsible for padding/alignment and span checking; this is the
-    raw pallas_call wrapper.
+    F % 8 == 0, N % block_rows == 0 and 128-aligned block_rows/hist are
+    ops.py's responsibility; this is the raw pallas_call wrapper.
     """
-    n, feat = values.shape
-    if n % block_rows:
-        raise ValueError(f"N={n} not a multiple of block_rows={block_rows}")
-    if hist < block_rows and hist % 8:
-        raise ValueError("hist must be 8-aligned")
-    grid = (n // block_rows,)
-    kernel = functools.partial(_rolling_sum_kernel, hist=hist)
+    if interpret is None:
+        interpret = interpret_mode()
+    feat, n = values_t.shape
+    if n % block_rows or block_rows % 128 or hist % 128:
+        raise ValueError(
+            f"N={n}, block_rows={block_rows}, hist={hist} must be 128-aligned "
+            "with N a multiple of block_rows"
+        )
     return pl.pallas_call(
-        kernel,
-        grid=grid,
+        _rolling_sum_kernel,
+        grid=(n // block_rows,),
         in_specs=[
-            pl.BlockSpec((block_rows, 1), lambda b: (b, 0)),   # starts
-            pl.BlockSpec((block_rows, feat), lambda b: (b, 0)),  # values
+            pl.BlockSpec((1, block_rows), lambda b: (0, b)),  # starts
+            pl.BlockSpec((feat, block_rows), lambda b: (0, b)),  # values
         ],
-        out_specs=pl.BlockSpec((block_rows, feat), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, feat), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((hist, feat), jnp.float32)],
+        out_specs=pl.BlockSpec((feat, block_rows), lambda b: (0, b)),
+        out_shape=jax.ShapeDtypeStruct((feat, n), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((feat, hist), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
         interpret=interpret,
-    )(starts.reshape(n, 1).astype(jnp.int32), values)
+    )(starts, values_t)

@@ -1,11 +1,11 @@
 """jit'd public wrapper around the rolling-window aggregation kernel.
 
-Handles everything the raw kernel does not: feature-dim padding to lane
-multiples, row padding to block multiples, span bucketing (the kernel needs a
-static history depth >= the maximum window row-span), and the derived
-aggregations (count is closed-form; mean = sum / count; min/max fall back to
-an XLA segment formulation — the prefix trick does not apply to them, which we
-document rather than hide).
+Handles everything the raw kernel does not: the transposed (F, N) layout
+with features padded to 8 sublanes, row padding to block multiples, span
+bucketing (the kernel needs a static history depth >= the maximum window
+row-span), the VMEM bound on that depth, and the derived aggregations
+(count is closed-form; mean = sum / count; min/max use an XLA
+doubling formulation — the windowed-sum matmul does not apply to them).
 """
 
 from __future__ import annotations
@@ -16,13 +16,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.rolling_agg.kernel import rolling_sum_kernel_call
-from repro.kernels.rolling_agg import ref as ref_mod
+from repro.kernels.rolling_agg.kernel import VMEM_LIMIT_BYTES, rolling_sum_kernel_call
 
-__all__ = ["rolling_sum", "rolling_sum_xla", "rolling_agg", "window_starts"]
+__all__ = [
+    "max_hist",
+    "rolling_agg",
+    "rolling_extreme",
+    "rolling_sum",
+    "rolling_sum_xla",
+    "window_starts",
+]
 
 _LANE = 128
+_SUBLANE = 8
 _DEFAULT_BLOCK = 256
+_SCAN_BLOCK = 1024  # rows per block of rolling_sum_xla's two-level scan
 
 
 def _round_up(x: int, m: int) -> int:
@@ -53,54 +61,147 @@ def window_starts(
     return starts.astype(np.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows", "hist", "interpret"))
+def _vmem_working_set(hist: int, block_rows: int, feat: int) -> int:
+    """Bytes of VMEM one grid step needs, fitted to what the v5e compiler
+    accepts at the kernel's limit: about four f32 copies of the (F, H+B)
+    extended block (carry, concatenation, matmul operand and the spills
+    around them) plus one (H+B, B) window mask."""
+    m = hist + block_rows
+    return 4 * (4 * feat * m + m * block_rows)
+
+
+def max_hist(feat: int, block_rows: int = _DEFAULT_BLOCK) -> int:
+    """Deepest history (a power of two >= 128) whose working set fits three
+    quarters of the kernel's VMEM limit for ``feat`` features — spans
+    deeper than this take the XLA path.  At the default block this is
+    16384 rows for up to 8 features, 4096 for 128, 1024 for 1024."""
+    fp = _round_up(max(feat, 1), _SUBLANE)
+    blk = _round_up(block_rows, _LANE)
+    h = _LANE
+    while _vmem_working_set(2 * h, blk, fp) <= VMEM_LIMIT_BYTES * 3 // 4:
+        h *= 2
+    return h
+
+
+@functools.partial(jax.jit, static_argnames=("block_rows", "hist"))
 def rolling_sum(
     values: jnp.ndarray,
     starts: jnp.ndarray,
     *,
     block_rows: int = _DEFAULT_BLOCK,
     hist: int = _DEFAULT_BLOCK,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Rolling-window sum.  values (N, F); starts (N,) int32; spans <= hist.
 
-    Returns float32 (N, F).  Padding: rows to block multiple (pad rows use
-    start=index so their window is empty+self over zero values), features to
-    the 128-lane multiple.
+    Returns float32 (N, F).  Padding: rows to the (128-aligned) block
+    multiple (pad rows use start=index so their window is empty+self over
+    zero values), features to 8 sublanes of the transposed layout; ``hist``
+    rounds up to 128 lanes.
     """
     n, feat = values.shape
-    n_pad = _round_up(max(n, 1), block_rows)
-    f_pad = _round_up(max(feat, 1), _LANE)
-    vals_p = jnp.zeros((n_pad, f_pad), values.dtype)
-    vals_p = vals_p.at[:n, :feat].set(values)
+    blk = _round_up(block_rows, _LANE)
+    n_pad = _round_up(max(n, 1), blk)
+    f_pad = _round_up(max(feat, 1), _SUBLANE)
+    vals_t = jnp.zeros((f_pad, n_pad), jnp.float32)
+    vals_t = vals_t.at[:feat, :n].set(values.astype(jnp.float32).T)
     starts_p = jnp.arange(n_pad, dtype=jnp.int32)
     starts_p = starts_p.at[:n].set(starts.astype(jnp.int32))
     out = rolling_sum_kernel_call(
-        vals_p, starts_p, block_rows=block_rows, hist=hist, interpret=interpret
+        vals_t, starts_p[None, :], block_rows=blk, hist=_round_up(hist, _LANE)
     )
-    return out[:n, :feat]
+    return out[:feat, :n].T
+
+
+def _two_sum(a: jnp.ndarray, b: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """a + b = s + err exactly in float32 (Knuth's TwoSum)."""
+    s = a + b
+    bv = s - a
+    return s, (a - (s - bv)) + (b - bv)
+
+
+def _add_double_float(x, y):
+    """Sum of two (hi, lo) float32 pairs, renormalized so |lo| <= ulp(hi)/2."""
+    s, e = _two_sum(x[0], y[0])
+    e = e + (x[1] + y[1])
+    hi = s + e
+    return hi, e - (hi - s)
 
 
 @jax.jit
 def rolling_sum_xla(values: jnp.ndarray, starts: jnp.ndarray) -> jnp.ndarray:
-    """O(N·F) prefix-difference on the XLA path (no Pallas): the same
-    P[i+1]-P[starts[i]] identity the kernel uses, via cumsum + gather.
-    Long-column catastrophic cancellation is why the Pallas kernel re-zeroes
-    its prefix every block (kernel.py) — this fallback accepts fp32 drift."""
+    """O(N·F) prefix-difference on the XLA path (no Pallas): the windowed
+    sum as P[i+1]-P[starts[i]] via a prefix scan + gather.  A float32
+    prefix over millions of rows would carry errors far larger than a
+    window's sum, so the prefix is a double-float (hi, lo) pair scan
+    (about 48 significant bits) and the difference is taken on both
+    halves.
+
+    The TPU compiler's time for a scan grows with the scanned length (about
+    400 s for one scan over 6 x 2^20 rows on v5e), so the rows are scanned
+    in blocks of ``_SCAN_BLOCK`` and then the N / ``_SCAN_BLOCK`` block
+    totals are scanned."""
     v = values.astype(jnp.float32)
-    p_inc = jnp.cumsum(v, axis=0)
-    p_exc = jnp.concatenate([jnp.zeros((1, v.shape[1]), v.dtype), p_inc], axis=0)
-    ends = p_exc[1 + jnp.arange(values.shape[0])]
-    return (ends - p_exc[starts]).astype(jnp.float32)
+    n, feat = v.shape
+    nb = n // _SCAN_BLOCK + 1  # room for P[n], one past the last row
+    v = jnp.pad(v, ((0, nb * _SCAN_BLOCK - n), (0, 0)))
+    v = v.reshape(nb, _SCAN_BLOCK, feat)
+    scan = functools.partial(jax.lax.associative_scan, _add_double_float)
+    local = scan((v, jnp.zeros_like(v)), axis=1)  # inclusive, within a block
+    totals = scan((local[0][:, -1], local[1][:, -1]), axis=0)
+    zero = jnp.zeros((1, feat), jnp.float32)
+    # offset[b] = sum of the blocks before b; shifted[i] = local sum at i - 1
+    offset = [jnp.concatenate([zero, t[:-1]]) for t in totals]
+    shifted = [jnp.concatenate([zero, t.reshape(-1, feat)]) for t in local]
+
+    def prefix(i):  # P[i] = sum of rows [0, i), as a (hi, lo) pair
+        b = i // _SCAN_BLOCK
+        j = jnp.where(i % _SCAN_BLOCK == 0, 0, i)  # a block's first row: 0
+        return _add_double_float(
+            (offset[0][b], offset[1][b]), (shifted[0][j], shifted[1][j])
+        )
+
+    end_hi, end_lo = prefix(jnp.arange(1, n + 1))
+    start_hi, start_lo = prefix(starts)
+    return (end_hi - start_hi) + (end_lo - start_lo)
 
 
-def _pick_hist(max_span: int, block_rows: int) -> int:
-    """Static history depth: next power-of-two multiple of 8 covering the
-    span, so recompilation is bounded to O(log(max span)) variants."""
-    h = 8
+@functools.partial(jax.jit, static_argnames=("levels", "agg"))
+def rolling_extreme(
+    values: jnp.ndarray, starts: jnp.ndarray, *, levels: int, agg: str
+) -> jnp.ndarray:
+    """Windowed min/max in O(N·F·levels) on the XLA path: level k holds the
+    extreme of the 2^k rows ending at each row (doubling), and a window of
+    span L in [2^k, 2^(k+1)) is the extreme of two overlapping level-k
+    windows, one ending at the row and one starting at its window start.
+    ``levels`` = bit length of the largest span."""
+    op, fill = (jnp.maximum, -jnp.inf) if agg == "max" else (jnp.minimum, jnp.inf)
+    v = values.astype(jnp.float32)
+    n, feat = v.shape
+    table = [v]
+    for k in range(1, levels):
+        sh = 1 << (k - 1)
+        prev = table[-1]
+        shifted = jnp.concatenate([jnp.full((sh, feat), fill), prev[:-sh]])[:n]
+        table.append(op(prev, shifted))
+    table = jnp.stack(table)  # (levels, N, F)
+    rows = jnp.arange(n)
+    span = rows + 1 - starts
+    k = (jnp.floor(jnp.log2(span.astype(jnp.float32)))).astype(jnp.int32)
+    # float log2 can land one off at exact powers of two; correct it exactly
+    k = k + ((1 << (k + 1)) <= span) - ((1 << k) > span)
+    k = jnp.clip(k, 0, levels - 1)
+    tail = table[k, rows]
+    head = table[k, starts + (1 << k) - 1]
+    return op(tail, head)
+
+
+def _pick_hist(max_span: int) -> int:
+    """Static history depth: next power of two >= the span (and >= 128
+    lanes), so recompilation is bounded to O(log(max span)) variants."""
+    h = _LANE
     while h < max_span:
         h *= 2
-    return max(h, 8)
+    return h
 
 
 def rolling_agg(
@@ -109,15 +210,17 @@ def rolling_agg(
     agg: str,
     *,
     block_rows: int = _DEFAULT_BLOCK,
-    interpret: bool = True,
     backend: str = "pallas",
+    monitor=None,
 ) -> jnp.ndarray:
     """Public entry used by the DSL executor.  ``starts`` must be host-side
     (numpy) — the DSL computes it from store-resident timestamps — which lets
     us pick the static history bucket and validate spans eagerly.
 
-    backend: 'pallas' (TPU target; interpret=True on CPU) or 'xla' (the
-    cumsum fallback — what a mesh without the kernel would run)."""
+    backend: 'pallas' (the kernel; compiled on TPU, interpreted elsewhere)
+    or 'xla' (the cumsum formulation).  A 'pallas' sum whose spans are
+    deeper than ``max_hist`` allows takes the XLA path too, and reports it
+    through ``monitor.record_kernel_fallback`` when a monitor is given."""
     starts = np.asarray(starts)
     n = values.shape[0]
     if n == 0:
@@ -132,12 +235,11 @@ def rolling_agg(
         return jnp.broadcast_to(cnt[:, None], values.shape).astype(jnp.float32)
 
     if agg in ("sum", "mean"):
-        hist = _pick_hist(max_span, block_rows)
-        if backend == "xla":
-            s = rolling_sum_xla(values, jnp.asarray(starts, jnp.int32))
-        elif hist > 4096:
-            # Span too deep for a VMEM history buffer: stay on the XLA
-            # path rather than claim an unrealistic VMEM footprint.
+        hist = _pick_hist(max_span)
+        deep = hist > max_hist(values.shape[1], block_rows)
+        if backend == "pallas" and deep and monitor is not None:
+            monitor.record_kernel_fallback("rolling_agg")
+        if backend == "xla" or deep:
             s = rolling_sum_xla(values, jnp.asarray(starts, jnp.int32))
         else:
             s = rolling_sum(
@@ -145,7 +247,6 @@ def rolling_agg(
                 jnp.asarray(starts, dtype=jnp.int32),
                 block_rows=block_rows,
                 hist=hist,
-                interpret=interpret,
             )
         if agg == "sum":
             return s
@@ -153,8 +254,9 @@ def rolling_agg(
         return s / jnp.maximum(cnt, 1.0)
 
     if agg in ("min", "max"):
-        # Prefix-difference does not apply to min/max; use the jnp oracle
-        # formulation (XLA lowers this as masked reductions).
-        return ref_mod.rolling_agg_ref(values, jnp.asarray(starts), agg)
+        return rolling_extreme(
+            values, jnp.asarray(starts, jnp.int32),
+            levels=max_span.bit_length(), agg=agg,
+        )
 
     raise ValueError(f"unknown agg {agg!r}")

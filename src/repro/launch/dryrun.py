@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: prove the distribution config is coherent.
 
 For every (architecture x input-shape) cell, against both production meshes
@@ -16,13 +13,14 @@ For every (architecture x input-shape) cell, against both production meshes
 Results accumulate into a JSON file consumed by EXPERIMENTS.md's §Dry-run /
 §Roofline tables and by benchmarks/roofline_summary.
 
-NOTE: the XLA_FLAGS line above MUST precede every other import (jax locks
-the device count at first init) — and must never be set for the test /
-benchmark processes, which expect 1 device.
+``main`` asks for 512 host devices through XLA_FLAGS before anything
+touches a jax backend (jax fixes the device count when the backend starts);
+importing this module changes nothing.
 """
 
 import argparse
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -45,7 +43,7 @@ def shardings_for(kind, cfg, args, mesh):
     if kind == "train":
         state, batch = args
         pspec = shd.param_specs(state.params, cfg, mesh)
-        opt_spec = opt_state_specs(state.opt, pspec, mesh)
+        opt_spec = shd.opt_state_specs(state.opt, pspec, mesh)
         state_spec = TrainState(params=pspec, opt=opt_spec, step=P())
         in_specs = (state_spec, shd.batch_specs(batch, mesh))
         out_specs = (state_spec, P())  # metrics replicated
@@ -64,56 +62,6 @@ def shardings_for(kind, cfg, args, mesh):
         out_specs = (None, cspec)
         donate = (1,)
     return in_specs, out_specs, donate
-
-
-def opt_state_specs(opt_shape, param_specs_tree, mesh=None):
-    """Optimizer-state specs mirroring the param specs (quantized moments:
-    q inherits the param spec, per-block scales drop the last-dim shard).
-
-    ZeRO-across-pod: params replicate over ``pod`` (gradients cross pods
-    once per step), but optimizer MOMENTS need not — each pod owns a slice
-    (first spec-free dim divisible by the pod count; for scanned stacks
-    that's the layer dim).  GSPMD turns the update into reduce-scatter(grad
-    over pod) + update + all-gather(params) — exactly ZeRO-1.  Halves the
-    biggest per-device state term on the 671B multi-pod cell."""
-
-    def _pod_shard(ps, shape) -> P:
-        if (
-            mesh is None
-            or "pod" not in getattr(mesh, "axis_names", ())
-            or mesh.shape["pod"] == 1
-        ):
-            return ps
-        npod = mesh.shape["pod"]
-        entries = list(ps) + [None] * (len(shape) - len(tuple(ps)))
-        for i, (e, dim) in enumerate(zip(entries, shape)):
-            if e is None and dim % npod == 0 and dim >= npod:
-                entries[i] = "pod"
-                return P(*entries)
-        return ps
-
-    def mirror_moment(ps, leaf):
-        if isinstance(leaf, dict):  # {"q": ..., "scale": ...}
-            qs = _pod_shard(ps, leaf["q"].shape)
-            scale_spec = (
-                P(*(tuple(qs)[:-1] + (None,))) if len(tuple(qs)) else P()
-            )
-            return {"q": qs, "scale": scale_spec}
-        return _pod_shard(ps, leaf.shape)
-
-    import jax as _jax
-
-    def mirror(moment_tree):
-        # walk the param-spec tree (specs are leaves) against the moment
-        # tree, whose leaves are arrays or {"q","scale"} dicts per param.
-        flat_specs, treedef = _jax.tree_util.tree_flatten(
-            param_specs_tree, is_leaf=lambda x: isinstance(x, P)
-        )
-        flat_moments = treedef.flatten_up_to(moment_tree)
-        out = [mirror_moment(s, m) for s, m in zip(flat_specs, flat_moments)]
-        return treedef.unflatten(out)
-
-    return {"count": P(), "m": mirror(opt_shape["m"]), "v": mirror(opt_shape["v"])}
 
 
 #: full-depth unrolled lowering is used up to this many layers; deeper
@@ -267,6 +215,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, *, reduced: bool = False) ->
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=list_archs() + ["all"], default="all")
     ap.add_argument("--shape", choices=list(SHAPES) + ["all"], default="all")

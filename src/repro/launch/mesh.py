@@ -25,12 +25,18 @@ __all__ = ["make_production_mesh", "make_mesh", "batch_axes", "axis_size"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Elastic variant for tests (e.g. (2,2,2) on 8 host devices)."""
-    return jax.make_mesh(shape, axes)
+    """Elastic variant for tests (e.g. (2,2,2) on 8 host devices).
+
+    Axes are Auto: the models place activations with
+    ``with_sharding_constraint`` and let GSPMD propagate the rest, which
+    ``jax.make_mesh``'s default Explicit axes reject."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def batch_axes(mesh) -> tuple[str, ...]:

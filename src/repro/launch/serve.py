@@ -24,6 +24,7 @@ from repro.configs import get_config, list_archs
 from repro.data.loader import HOUR, TokenFeatureSet
 from repro.data.sources import TokenEventSource
 from repro.core.featurestore import FeatureStore
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 
 
@@ -32,7 +33,7 @@ def build_serving_plane(cfg, *, seed: int = 0):
         "token_stream", seed=seed, vocab_size=cfg.vocab_size,
         num_docs=64, chunk_len=32, chunks_per_bucket=128,
     )
-    fs = FeatureStore("lm-serving-plane", interpret=True)
+    fs = FeatureStore("lm-serving-plane")
     fs.register_source(src)
     spec = fs.create_feature_set(TokenFeatureSet(src))
     fs.tick(now=3 * HOUR)
@@ -105,4 +106,5 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -26,6 +26,7 @@ from repro.configs import get_config, list_archs
 from repro.data.loader import HOUR, FeatureStoreLoader, TokenFeatureSet
 from repro.data.sources import TokenEventSource
 from repro.core.featurestore import FeatureStore
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.launch.steps import TrainState, make_train_step
 from repro.models import api
@@ -40,7 +41,7 @@ def build_data_plane(cfg, *, seq_len: int, batch: int, seed: int = 0):
         "token_stream", seed=seed, vocab_size=cfg.vocab_size,
         num_docs=256, chunk_len=64, chunks_per_bucket=512,
     )
-    fs = FeatureStore("lm-data-plane", interpret=True)
+    fs = FeatureStore("lm-data-plane")
     fs.register_source(src)
     spec = fs.create_feature_set(TokenFeatureSet(src))
     loader = FeatureStoreLoader(
@@ -100,11 +101,10 @@ def main(argv=None) -> dict:
 
     if mesh is not None:
         pspec = shd.param_specs(state.params, cfg, mesh)
-        from repro.launch.dryrun import opt_state_specs
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         sspec = TrainState(
-            params=pspec, opt=opt_state_specs(state.opt, pspec), step=P()
+            params=pspec, opt=shd.opt_state_specs(state.opt, pspec), step=P()
         )
         to_shd = lambda t: jax.tree.map(
             lambda s: NamedSharding(mesh, s), t, is_leaf=lambda x: isinstance(x, P)
@@ -165,4 +165,5 @@ class _null_ctx:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

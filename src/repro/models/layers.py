@@ -13,8 +13,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import ad_barrier
-
 __all__ = [
     "rms_norm",
     "layer_norm",
@@ -37,7 +35,7 @@ def reduce_boundary(x: jnp.ndarray, dtype=jnp.bfloat16) -> jnp.ndarray:
     probe, §Perf iter-4).  An optimization barrier on the bf16 value keeps
     the reduction bf16.  AD passes cotangents through the barrier, so the
     backward dot's all-reduce is bf16 too (the gradient-compression lever)."""
-    return ad_barrier(x.astype(dtype))
+    return jax.lax.optimization_barrier(x.astype(dtype))
 
 
 def dense_init(key, shape, fan_in: Optional[int] = None, dtype=jnp.bfloat16):

@@ -46,9 +46,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro.models.config import ModelConfig
 from repro.models.layers import dense_init

@@ -33,6 +33,7 @@ __all__ = [
     "batch_specs",
     "cache_specs",
     "tree_shardings",
+    "opt_state_specs",
 ]
 
 FSDP = "data"
@@ -229,3 +230,51 @@ def cache_specs(cache_shape: Any, cfg: ModelConfig, mesh) -> Any:
         return _divisible(spec, leaf.shape, mesh)
 
     return jax.tree_util.tree_map_with_path(one, cache_shape)
+
+
+def opt_state_specs(opt_shape, param_specs_tree, mesh=None):
+    """Optimizer-state specs mirroring the param specs (quantized moments:
+    q inherits the param spec, per-block scales drop the last-dim shard).
+
+    ZeRO-across-pod: params replicate over ``pod`` (gradients cross pods
+    once per step), but optimizer MOMENTS need not — each pod owns a slice
+    (first spec-free dim divisible by the pod count; for scanned stacks
+    that's the layer dim).  GSPMD turns the update into reduce-scatter(grad
+    over pod) + update + all-gather(params) — exactly ZeRO-1.  Halves the
+    biggest per-device state term on the 671B multi-pod cell."""
+
+    def _pod_shard(ps, shape) -> P:
+        if (
+            mesh is None
+            or "pod" not in getattr(mesh, "axis_names", ())
+            or mesh.shape["pod"] == 1
+        ):
+            return ps
+        npod = mesh.shape["pod"]
+        entries = list(ps) + [None] * (len(shape) - len(tuple(ps)))
+        for i, (e, dim) in enumerate(zip(entries, shape)):
+            if e is None and dim % npod == 0 and dim >= npod:
+                entries[i] = "pod"
+                return P(*entries)
+        return ps
+
+    def mirror_moment(ps, leaf):
+        if isinstance(leaf, dict):  # {"q": ..., "scale": ...}
+            qs = _pod_shard(ps, leaf["q"].shape)
+            scale_spec = (
+                P(*(tuple(qs)[:-1] + (None,))) if len(tuple(qs)) else P()
+            )
+            return {"q": qs, "scale": scale_spec}
+        return _pod_shard(ps, leaf.shape)
+
+    def mirror(moment_tree):
+        # walk the param-spec tree (specs are leaves) against the moment
+        # tree, whose leaves are arrays or {"q","scale"} dicts per param.
+        flat_specs, treedef = jax.tree_util.tree_flatten(
+            param_specs_tree, is_leaf=lambda x: isinstance(x, P)
+        )
+        flat_moments = treedef.flatten_up_to(moment_tree)
+        out = [mirror_moment(s, m) for s, m in zip(flat_specs, flat_moments)]
+        return treedef.unflatten(out)
+
+    return {"count": P(), "m": mirror(opt_shape["m"]), "v": mirror(opt_shape["v"])}

@@ -28,9 +28,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro.optim.adamw import dequantize_q8, quantize_q8
 

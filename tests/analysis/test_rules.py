@@ -17,16 +17,19 @@ from repro.analysis.engine import run
 
 FIXTURES = Path(__file__).resolve().parent / "fixtures"
 
-# rule name -> (bug fixture, expected finding count, fixed fixture)
+# case -> (rule, bug fixture, expected finding count, fixed fixture)
 CASES = {
-    "aliasing": ("aliasing_bug.py", 1, "aliasing_fixed.py"),
-    "determinism": ("determinism_bug.py", 3, "determinism_fixed.py"),
-    "donation": ("donation_bug.py", 1, "donation_fixed.py"),
-    "gauge-keys": ("gauges_bug.py", 2, "gauges_fixed.py"),
-    "vacuous-gate": ("gates_bug.py", 4, "gates_fixed.py"),
-    "wire-format": ("wire_bug.py", 3, "wire_fixed.py"),
-    "frozen-stats": ("stats_bug.py", 1, "stats_fixed.py"),
-    "format": ("format_bug.py", 3, "format_fixed.py"),
+    "aliasing": ("aliasing", "aliasing_bug.py", 1, "aliasing_fixed.py"),
+    "determinism": ("determinism", "determinism_bug.py", 3, "determinism_fixed.py"),
+    "donation": ("donation", "donation_bug.py", 1, "donation_fixed.py"),
+    "gauge-keys": ("gauge-keys", "gauges_bug.py", 2, "gauges_fixed.py"),
+    "vacuous-gate": ("vacuous-gate", "gates_bug.py", 4, "gates_fixed.py"),
+    "wire-format": ("wire-format", "wire_bug.py", 3, "wire_fixed.py"),
+    "frozen-stats": ("frozen-stats", "stats_bug.py", 1, "stats_fixed.py"),
+    "format": ("format", "format_bug.py", 3, "format_fixed.py"),
+    "format-fstring": (
+        "format", "format_fstring_bug.py", 1, "format_fstring_fixed.py"
+    ),
 }
 
 
@@ -39,9 +42,9 @@ def _run(rule: str, filename: str):
     )
 
 
-@pytest.mark.parametrize("rule", sorted(CASES))
-def test_rule_fires_on_historical_bug(rule):
-    bug, expected, _ = CASES[rule]
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_rule_fires_on_historical_bug(case):
+    rule, bug, expected, _ = CASES[case]
     result = _run(rule, bug)
     assert len(result.findings) == expected, [
         f.render() for f in result.findings
@@ -50,9 +53,9 @@ def test_rule_fires_on_historical_bug(rule):
     assert all(f.line > 0 for f in result.findings)
 
 
-@pytest.mark.parametrize("rule", sorted(CASES))
-def test_rule_silent_on_shipped_fix(rule):
-    _, _, fixed = CASES[rule]
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_rule_silent_on_shipped_fix(case):
+    rule, _, _, fixed = CASES[case]
     result = _run(rule, fixed)
     assert result.findings == [], [f.render() for f in result.findings]
     assert result.clean
@@ -62,7 +65,7 @@ def test_every_registered_rule_has_a_fixture_pair():
     from repro.analysis.registry import RULES
     from repro.analysis import rules as _rules  # noqa: F401 - registration
 
-    assert set(RULES) == set(CASES)
+    assert set(RULES) == {rule for rule, *_ in CASES.values()}
 
 
 # -- pinned messages: the finding must name the defect, not just point ------

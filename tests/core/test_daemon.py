@@ -38,7 +38,7 @@ from repro.core.assets import (
     MaterializationSettings,
 )
 from repro.core.channel import FaultPlan
-from repro.core.daemon import SocketChannel, spawn_replica_daemon
+from repro.core.daemon import ReplicaDaemon, SocketChannel, spawn_replica_daemon
 from repro.core.dsl import UDFTransform
 from repro.core.offline_store import OfflineStore
 from repro.core.online_store import OnlineStore
@@ -293,3 +293,13 @@ def test_daemon_teardown_leaves_no_orphan():
     assert h.proc.poll() is not None
     with pytest.raises(ProcessLookupError):
         os.kill(pid, 0)
+
+
+def test_daemon_refuses_the_device_engine():
+    """A replica daemon is a host process: the kernel engine, which would
+    take an accelerator the parent may hold, is refused before any child
+    starts."""
+    with pytest.raises(ValueError, match="host process"):
+        spawn_replica_daemon(region="eastus", merge_engine="kernel")
+    with pytest.raises(ValueError, match="host process"):
+        ReplicaDaemon(region="eastus", merge_engine="kernel")

@@ -134,7 +134,7 @@ def test_staleness_reflects_schedule_lag():
     from repro.data.sources import SyntheticEventSource
 
     HOUR = 3_600_000
-    fs = FeatureStore("stale", interpret=True)
+    fs = FeatureStore("stale")
     fs.register_source(SyntheticEventSource("tx", num_entities=4,
                                             events_per_bucket=10))
     fs.create_feature_set(FeatureSetSpec(

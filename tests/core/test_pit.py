@@ -87,6 +87,30 @@ def test_tie_break_prefers_latest_creation():
     assert res.found[0] and res.values["val"][0] == 2.0
 
 
+def test_span_beyond_int32_falls_back_and_is_counted():
+    """Timestamps the kernel's int32 domain cannot rebase take the oracle,
+    answer the same, and the monitor counts the fallback; a span that fits
+    is no fallback."""
+    from repro.core.monitoring import HealthMonitor
+
+    spec = make_spec()
+    far = 2**33
+    hist = history_table([1, 1, 2], [0, far, 5], [1, 1, 1], [1.0, 2.0, 3.0])
+    monitor = HealthMonitor()
+    res = pit_join_feature_set(
+        [np.array([1, 1, 2])], np.array([10, far + 1, 4]), spec, hist,
+        monitor=monitor,
+    )
+    np.testing.assert_array_equal(res.found, [True, True, False])
+    np.testing.assert_array_equal(res.values["val"][:2], [1.0, 2.0])
+    assert monitor.kernel_fallbacks() == {"pit_join": 1.0}
+    near = history_table([1], [0], [1], [1.0])
+    pit_join_feature_set(
+        [np.array([1])], np.array([10]), spec, near, monitor=monitor
+    )
+    assert monitor.kernel_fallbacks() == {"pit_join": 1.0}
+
+
 def test_multi_feature_set_spine_join():
     store = OfflineStore(num_shards=2)
     spec_a, spec_b = make_spec(), None

@@ -22,9 +22,7 @@ The tentpole claims under test:
   * FACADE — ``FeatureStore``, ``GeoFeatureStore`` and
     ``MultiHomeGeoStore`` all satisfy the unified ``StoreFacade`` surface.
 
-Property tests run under ``hypothesis`` when installed, else the seeded
-deterministic fallback from ``tests/conftest.py`` — either way they always
-execute.
+Routing properties run under ``hypothesis``.
 """
 
 import numpy as np
@@ -105,7 +103,7 @@ def assert_mesh_identical(mh, ctx=""):
             )
 
 
-# -- routing properties (hypothesis or the conftest fallback) -----------------
+# -- routing properties (hypothesis) ------------------------------------------
 
 
 @settings(max_examples=60, deadline=None)

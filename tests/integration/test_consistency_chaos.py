@@ -20,7 +20,7 @@ SEAMS = ("before_compute", "after_compute", "between_merges", "after_merges")
 
 
 def _store(seed=0, online=True, offline=True) -> FeatureStore:
-    fs = FeatureStore("chaos", interpret=True)
+    fs = FeatureStore("chaos")
     src = SyntheticEventSource("tx", seed=seed, num_entities=12,
                                events_per_bucket=40)
     fs.register_source(src)

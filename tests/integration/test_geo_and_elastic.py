@@ -119,6 +119,7 @@ _ELASTIC_SCRIPT = textwrap.dedent(
 
     from repro.checkpoint.manager import restore_checkpoint, save_checkpoint
     from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
     from repro.launch.steps import TrainState, make_train_step
     from repro.models import api
     from repro.models import sharding as shd
@@ -136,14 +137,13 @@ _ELASTIC_SCRIPT = textwrap.dedent(
 
     def place(state, mesh):
         pspec = shd.param_specs(state.params, cfg, mesh)
-        from repro.launch.dryrun import opt_state_specs
-        sspec = TrainState(pspec, opt_state_specs(state.opt, pspec), P())
+        sspec = TrainState(pspec, shd.opt_state_specs(state.opt, pspec), P())
         shards = jax.tree.map(lambda s: NamedSharding(mesh, s), sspec,
                               is_leaf=lambda x: isinstance(x, P))
         return jax.device_put(state, shards), shards
 
     # run 2 steps on a 4x2 mesh, checkpoint
-    mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_a = make_mesh((4, 2), ("data", "model"))
     state_a, shards_a = place(state, mesh_a)
     with mesh_a, activation_mesh(mesh_a):
         jit_a = jax.jit(step)
@@ -153,7 +153,7 @@ _ELASTIC_SCRIPT = textwrap.dedent(
     save_checkpoint(d, 2, state_a)
 
     # restore onto a DIFFERENT (2x4) mesh and continue
-    mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+    mesh_b = make_mesh((2, 4), ("data", "model"))
     template = jax.eval_shape(lambda: TrainState.create(
         api.init_params(jax.random.PRNGKey(0), cfg), opt))
     _, shards_b = place(jax.tree.map(np.zeros_like,

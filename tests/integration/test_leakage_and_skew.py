@@ -24,7 +24,7 @@ HOUR = 3_600_000
 def _lm_plane(seed=0):
     src = TokenEventSource("tok", seed=seed, vocab_size=512, num_docs=32,
                            chunk_len=16, chunks_per_bucket=64)
-    fs = FeatureStore("leak-test", interpret=True)
+    fs = FeatureStore("leak-test")
     fs.register_source(src)
     spec = fs.create_feature_set(TokenFeatureSet(src))
     loader = FeatureStoreLoader(store=fs, spec=spec, seq_len=32, batch_size=4,
@@ -57,7 +57,7 @@ def test_clock_monotonicity_and_determinism():
 
 def test_online_equals_offline_latest():
     """§4.5.2: online must serve max(tuple(event_ts, creation_ts)) per id."""
-    fs = FeatureStore("skew-test", interpret=True)
+    fs = FeatureStore("skew-test")
     src = SyntheticEventSource("tx", num_entities=24, events_per_bucket=120)
     fs.register_source(src)
     fs.create_feature_set(
@@ -91,7 +91,7 @@ def test_expected_delay_holds_back_late_data():
     """A feature set with expected_delay D must not serve values within D of
     the observation time (the paper's 'expected delay of source and feature
     data')."""
-    fs = FeatureStore("delay-test", interpret=True)
+    fs = FeatureStore("delay-test")
     src = SyntheticEventSource("tx", num_entities=8, events_per_bucket=60)
     fs.register_source(src)
     fs.create_feature_set(

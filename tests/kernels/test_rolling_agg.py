@@ -1,6 +1,7 @@
 """rolling_agg kernel vs pure-jnp oracle: shape/dtype sweeps + properties.
 
-All Pallas execution is interpret=True (CPU container; TPU is the target).
+Pallas runs in interpret mode here: the mode follows the backend (CPU here;
+the kernels compile on a TPU).
 """
 
 import jax.numpy as jnp
@@ -8,8 +9,15 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.core.monitoring import HealthMonitor
 from repro.kernels.rolling_agg import ref as R
-from repro.kernels.rolling_agg.ops import rolling_agg, rolling_sum, window_starts
+from repro.kernels.rolling_agg.ops import (
+    max_hist,
+    rolling_agg,
+    rolling_sum,
+    rolling_sum_xla,
+    window_starts,
+)
 
 
 def _random_case(rng, n, feat, n_seg, window, dtype=np.float32):
@@ -108,13 +116,33 @@ def test_rolling_sum_block_hist_sweep(block_rows, hist):
 
 def test_rolling_agg_deep_span_falls_back():
     """Spans deeper than the VMEM history bucket use the XLA path but stay
-    correct."""
-    n = 600
+    correct, and the monitor counts the fallback."""
+    n = max_hist(2) + 1
     vals = np.ones((n, 2), np.float32)
     starts = np.zeros(n, np.int32)  # every window reaches row 0: span = n
-    got = rolling_agg(jnp.asarray(vals), starts, "sum")
+    monitor = HealthMonitor()
+    got = rolling_agg(jnp.asarray(vals), starts, "sum", monitor=monitor)
     want = (np.arange(n) + 1).astype(np.float32)
     np.testing.assert_allclose(got[:, 0], want, rtol=1e-6)
+    assert monitor.kernel_fallbacks() == {"rolling_agg": 1.0}
+    # a span the kernel holds is no fallback
+    rolling_agg(jnp.asarray(vals[:600]), starts[:600], "sum", monitor=monitor)
+    assert monitor.kernel_fallbacks() == {"rolling_agg": 1.0}
+
+
+def test_rolling_sum_xla_long_column_is_exact():
+    """Short windows deep in a long, large-valued column: a float32 prefix
+    would be off by several units here; the blocked double-float prefix
+    stays within float32 rounding of each window's own sum, also for
+    windows that straddle a scan block."""
+    rng = np.random.default_rng(3)
+    n = 3 * 1024 + 5
+    vals = (1e4 + rng.gamma(2.0, 50.0, (n, 2))).astype(np.float32)
+    starts = np.maximum(0, np.arange(n) - rng.integers(0, 50, n)).astype(np.int32)
+    got = np.asarray(rolling_sum_xla(jnp.asarray(vals), jnp.asarray(starts)))
+    prefix = np.concatenate([np.zeros((1, 2)), np.cumsum(vals, 0, np.float64)])
+    want = prefix[1:] - prefix[starts]
+    np.testing.assert_allclose(got, want, rtol=1e-6)
 
 
 # ---------------------------------------------------------------------------

@@ -94,6 +94,7 @@ _EP_SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     from repro.models import moe
     from repro.models.config import ModelConfig
     from repro.models.pspec import activation_mesh
@@ -109,7 +110,7 @@ _EP_SCRIPT = textwrap.dedent(
 
     y_ref, a_ref = moe.moe_apply_einsum(p, x, cfg, group_size=64,
                                         capacity_factor=8.0)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with mesh, activation_mesh(mesh):
         y_ep, a_ep = jax.jit(
             lambda p, x: moe.moe_apply(p, x, cfg, group_size=64,
