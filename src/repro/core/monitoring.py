@@ -8,17 +8,38 @@ Latency distributions are tracked by ``BoundedHistogram``: geometric
 buckets of fixed relative width, so the serving front can observe every
 request's stage latencies forever (p50/p99/p999) in O(1) memory instead of
 accumulating one float per sample.
+
+Spans inside the program are kept by ``SpanRecorder`` (one per process,
+``SPANS``): while a JAX profiler session is active, each span is written
+into the profiler's host trace, on the device events' clock, and kept in a
+bounded in-memory buffer with its parent, root and attributes.
 """
 
 from __future__ import annotations
 
+import itertools
 import math
+import threading
+import time
 from collections import defaultdict
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import numpy as np
+from jax._src import profiler as _jax_profiler
+from jax.profiler import TraceAnnotation
 
-__all__ = ["BoundedHistogram", "Metrics", "HealthMonitor"]
+# where jax.profiler.start_trace keeps its session; None where a JAX
+# version moves it, and then a new session does not reset the buffer
+_PROFILE_STATE = getattr(_jax_profiler, "_profile_state", None)
+
+__all__ = [
+    "BoundedHistogram",
+    "HealthMonitor",
+    "Metrics",
+    "SPANS",
+    "Span",
+    "SpanRecorder",
+]
 
 
 class BoundedHistogram:
@@ -323,3 +344,163 @@ class HealthMonitor:
         failed = self.system.counters.get("jobs_failed", 0)
         ok = self.system.counters.get("jobs_succeeded", 0)
         return failed == 0 or ok / max(ok + failed, 1) > 0.95
+
+
+# -- spans ------------------------------------------------------------------
+
+
+class Span(NamedTuple):
+    """One recorded span: its id, the id of the innermost span open on its
+    thread when it started (``parent``, None for a root) and of its root
+    span (``root``: every span of one dispatch or one update shares it),
+    start and end in ``time.perf_counter_ns`` nanoseconds, and attributes."""
+
+    id: int
+    parent: Optional[int]
+    root: int
+    name: str
+    start_ns: int
+    end_ns: int
+    attrs: dict
+
+
+class _NullSpan:
+    """What ``span`` returns while nothing profiles the process: a shared,
+    falsy context whose ``set`` does nothing, so a call site can skip
+    computing attributes with ``if sp:``."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def __bool__(self) -> bool:
+        return False
+
+    def set(self, **attrs) -> None:
+        return None
+
+
+_NULL_SPAN = _NullSpan()
+
+
+class _OpenSpan:
+    __slots__ = ("rec", "name", "attrs", "id", "parent", "root", "start", "ann")
+
+    def __init__(self, rec: "SpanRecorder", name: str, attrs: dict) -> None:
+        self.rec = rec
+        self.name = name
+        self.attrs = attrs
+
+    def set(self, **attrs) -> None:
+        """Attributes known only inside the span (counts, outcomes)."""
+        self.attrs.update(attrs)
+
+    def __enter__(self):
+        rec = self.rec
+        stack = rec._stack()
+        self.id = next(rec._ids)
+        if stack:
+            self.parent = stack[-1].id
+            self.root = stack[-1].root
+        else:
+            self.parent = None
+            self.root = self.id
+        stack.append(self)
+        self.ann = TraceAnnotation(self.name)
+        self.ann.__enter__()
+        self.start = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end = time.perf_counter_ns()
+        self.ann.__exit__(*exc)
+        rec = self.rec
+        rec._stack().pop()
+        rec._keep(
+            Span(self.id, self.parent, self.root, self.name, self.start, end,
+                 self.attrs)
+        )
+
+
+class SpanRecorder:
+    """Process-wide spans at the program's layer boundaries, recorded only
+    while a JAX profiler session is active (``TraceAnnotation.is_enabled``):
+    tracing is on exactly while someone profiles the process, and needs no
+    setting of its own.
+
+    Off, ``span`` costs that one check and returns a shared null context:
+    no clock read, no allocation.  On, each span opens a
+    ``TraceAnnotation`` of its name, so it lands on the profiler's host
+    plane on the device events' clock (an idle gap on the device can be
+    charged to it), and keeps a ``Span`` in a buffer of at most
+    ``capacity`` spans; spans past that are counted in ``dropped``.  The
+    first span of a new profiler session (started with
+    ``jax.profiler.start_trace``) starts a fresh buffer, so the buffer holds
+    the last session's spans.  Names carry the prefix ``fs.``, which tells
+    them from the runtime's and a benchmark's own events in the trace."""
+
+    def __init__(self, capacity: int = 1 << 16) -> None:
+        self.capacity = int(capacity)
+        self.dropped = 0
+        self._buf: list[Span] = []
+        self._ids = itertools.count(1)
+        self._local = threading.local()
+        self._session = None
+
+    def span(self, name: str, **attrs):
+        """Context manager for one span; ``attrs`` and later
+        ``set(**attrs)`` calls become the span's attributes."""
+        if not TraceAnnotation.is_enabled():
+            return _NULL_SPAN
+        session = getattr(_PROFILE_STATE, "profile_session", None)
+        if session is not self._session:
+            self._fresh(session)
+        return _OpenSpan(self, name, attrs)
+
+    def _fresh(self, session) -> None:
+        # the recorder holds the session object, so a later session can
+        # never reuse its identity
+        self._session = session
+        self._buf = []
+        self.dropped = 0
+
+    def _stack(self) -> list:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def _keep(self, span: Span) -> None:
+        if len(self._buf) < self.capacity:
+            self._buf.append(span)
+        else:
+            self.dropped += 1
+
+    def spans(self, name: Optional[str] = None) -> list[Span]:
+        """The buffer's spans in the order they ended, or those of one name."""
+        buf = list(self._buf)
+        return buf if name is None else [s for s in buf if s.name == name]
+
+    def summary(self) -> dict[str, dict]:
+        """Per name: ``count``, ``total_ms`` and ``self_ms`` (each span's
+        duration less its children's that the buffer holds)."""
+        buf = list(self._buf)
+        child_ns: dict[int, int] = defaultdict(int)
+        for s in buf:
+            if s.parent is not None:
+                child_ns[s.parent] += s.end_ns - s.start_ns
+        out: dict[str, dict] = {}
+        for s in buf:
+            d = out.setdefault(s.name, {"count": 0, "total_ms": 0.0, "self_ms": 0.0})
+            dur = s.end_ns - s.start_ns
+            d["count"] += 1
+            d["total_ms"] += dur / 1e6
+            d["self_ms"] += (dur - child_ns.get(s.id, 0)) / 1e6
+        return out
+
+
+SPANS = SpanRecorder()

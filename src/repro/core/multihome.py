@@ -235,9 +235,6 @@ class MultiHomeGeoStore:
             if owner != region:
                 out["forwarded_rows"] += int(len(idx))
                 self.monitor.record_forwarded_write(region, owner, int(len(idx)))
-                self.monitor.system.observe(
-                    "multihome/forward_ms", self.topology.latency(region, owner)
-                )
         self.write_log["rows"] += len(frame)
         self.write_log["local_rows"] += out["slices"].get(region, 0)
         self.write_log["forwarded_rows"] += out["forwarded_rows"]
@@ -394,7 +391,6 @@ class MultiHomeGeoStore:
             found[idx] = f
             route["per_range"][int(sid)] = {"region": serving, "modeled_ms": ms}
             route["modeled_ms"] = max(route["modeled_ms"], ms)
-        self.monitor.system.observe("geo/read_modeled_ms", route["modeled_ms"])
         return vals, found, route
 
     # -- failure handling ----------------------------------------------------

@@ -46,6 +46,16 @@ the source of truth between kernel merges/lookups:
 ``transfers`` tallies every host<->device byte the store moves, so tests and
 benchmarks can assert the steady-state cycle is O(batch).
 
+Under a JAX profiler session the read and write paths record spans
+(``monitoring.SPANS``): ``fs.store.lookup`` around each ``lookup_encoded``,
+with ``fs.store.lookup.scan`` (query upload through the slot matrix's host
+copy) and ``fs.store.lookup.gather`` (``gather_rows`` through the rows'
+host copy) inside it on the kernel path; ``fs.store.merge`` around each
+``merge``/``merge_reduced``, listeners included, with
+``fs.store.merge.resolve`` around the index find and, on a stale mirror,
+the device gather of the old timestamps.  They end at host copies the
+path makes anyway and add no synchronisation.
+
 Write path — three interchangeable engines, byte-identical end states:
   * ``vector`` (default): core.merge_engine pre-reduces the batch to one
     winner per id (lexsort + segment scan), slots resolve in bulk against
@@ -84,6 +94,7 @@ import numpy as np
 from repro.core.assets import FeatureSetSpec
 from repro.core.keys import encode_keys
 from repro.core.merge_engine import merge_sorted, plan_online_batch
+from repro.core.monitoring import SPANS
 from repro.core.offline_store import CREATION_TS, EVENT_TS
 from repro.core.table import Table
 from repro.kernels.online_lookup import ops as lookup_ops
@@ -436,26 +447,34 @@ class OnlineStore:
         Algorithm-2 tallies plus the touched-slot coordinates and the reduced
         winner rows that landed there (sorted by (part, slot)) — the reduced
         batch form geo-replication ships."""
-        engine = engine or self.merge_engine
-        if engine not in ("vector", "kernel", "loop"):
-            raise ValueError(f"unknown merge engine {engine!r}")
-        self.register(spec)
-        if len(frame) == 0:
-            return self._empty_stats(engine, len(spec.features), creation_ts)
-        ids = encode_keys([frame[c] for c in spec.index_columns])
-        event_ts = frame[spec.timestamp_col].astype(np.int64)
-        fnames = [f.name for f in spec.features]
-        if engine == "loop":
-            feats = frame.column_stack(fnames, np.float32)
-            stats = self._merge_loop(spec.key, ids, event_ts, feats, creation_ts)
-        else:
-            stats = self._merge_vector(
-                spec.key, ids, event_ts, frame, fnames, creation_ts,
-                use_kernel=(engine == "kernel"),
-            )
-        for cb in self.merge_listeners:
-            cb(spec, stats)
-        return stats
+        with SPANS.span("fs.store.merge") as sp:
+            engine = engine or self.merge_engine
+            if engine not in ("vector", "kernel", "loop"):
+                raise ValueError(f"unknown merge engine {engine!r}")
+            self.register(spec)
+            if len(frame) == 0:
+                return self._empty_stats(engine, len(spec.features), creation_ts)
+            ids = encode_keys([frame[c] for c in spec.index_columns])
+            event_ts = frame[spec.timestamp_col].astype(np.int64)
+            fnames = [f.name for f in spec.features]
+            if engine == "loop":
+                feats = frame.column_stack(fnames, np.float32)
+                stats = self._merge_loop(spec.key, ids, event_ts, feats, creation_ts)
+            else:
+                stats = self._merge_vector(
+                    spec.key, ids, event_ts, frame, fnames, creation_ts,
+                    use_kernel=(engine == "kernel"),
+                )
+            for cb in self.merge_listeners:
+                cb(spec, stats)
+            if sp:
+                sp.set(
+                    rows=len(frame),
+                    inserts=stats.inserts,
+                    overrides=stats.overrides,
+                    noops=stats.noops,
+                )
+            return stats
 
     def merge_reduced(
         self,
@@ -477,34 +496,44 @@ class OnlineStore:
         batch goes through the SAME Algorithm-2 engines as ``merge``, so
         re-delivered or out-of-order batches converge: latest-wins on
         (event_ts, creation_ts) is an idempotent, commutative join."""
-        engine = engine or self.merge_engine
-        if engine not in ("vector", "kernel", "loop"):
-            raise ValueError(f"unknown merge engine {engine!r}")
-        self.register(spec)
-        keys = np.asarray(keys, np.int64)
-        event_ts = np.asarray(event_ts, np.int64)
-        values = np.asarray(values, np.float32)
-        if values.shape != (len(keys), len(spec.features)):
-            raise ValueError(
-                f"values plane {values.shape} does not match "
-                f"({len(keys)}, {len(spec.features)})"
-            )
-        if len(keys) and keys.min() < 0:
-            raise ValueError("reduced-batch keys must be encoded (non-negative)")
-        if len(keys) == 0:
-            return self._empty_stats(engine, len(spec.features), creation_ts)
-        if engine == "loop":
-            stats = self._merge_loop(spec.key, keys, event_ts, values, creation_ts)
-        else:
-            fnames = [f.name for f in spec.features]
-            frame = {n: values[:, j] for j, n in enumerate(fnames)}
-            stats = self._merge_vector(
-                spec.key, keys, event_ts, frame, fnames, creation_ts,
-                use_kernel=(engine == "kernel"),
-            )
-        for cb in self.merge_listeners:
-            cb(spec, stats)
-        return stats
+        with SPANS.span("fs.store.merge") as sp:
+            engine = engine or self.merge_engine
+            if engine not in ("vector", "kernel", "loop"):
+                raise ValueError(f"unknown merge engine {engine!r}")
+            self.register(spec)
+            keys = np.asarray(keys, np.int64)
+            event_ts = np.asarray(event_ts, np.int64)
+            values = np.asarray(values, np.float32)
+            if values.shape != (len(keys), len(spec.features)):
+                raise ValueError(
+                    f"values plane {values.shape} does not match "
+                    f"({len(keys)}, {len(spec.features)})"
+                )
+            if len(keys) and keys.min() < 0:
+                raise ValueError("reduced-batch keys must be encoded (non-negative)")
+            if len(keys) == 0:
+                return self._empty_stats(engine, len(spec.features), creation_ts)
+            if engine == "loop":
+                stats = self._merge_loop(
+                    spec.key, keys, event_ts, values, creation_ts
+                )
+            else:
+                fnames = [f.name for f in spec.features]
+                frame = {n: values[:, j] for j, n in enumerate(fnames)}
+                stats = self._merge_vector(
+                    spec.key, keys, event_ts, frame, fnames, creation_ts,
+                    use_kernel=(engine == "kernel"),
+                )
+            for cb in self.merge_listeners:
+                cb(spec, stats)
+            if sp:
+                sp.set(
+                    rows=len(keys),
+                    inserts=stats.inserts,
+                    overrides=stats.overrides,
+                    noops=stats.noops,
+                )
+            return stats
 
     @staticmethod
     def _empty_stats(engine: str, d: int, creation_ts: int) -> MergeStats:
@@ -539,29 +568,34 @@ class OnlineStore:
             dev = None
 
         def resolve(uids: np.ndarray):
-            part_e, slot_e, found = self._index_find(t, uids)
-            resolve.parts, resolve.slots = part_e, slot_e
-            if t.host_stale:
-                # host mirror is behind device truth: O(batch) coord gather
-                g = len(uids)
-                gb = _bucket(g)
-                p32 = np.zeros(gb, np.int32)
-                s32 = np.zeros(gb, np.int32)
-                p32[:g] = part_e
-                s32[:g] = slot_e
-                planes = merge_ops.gather_slot_ts(
-                    dev.ev_lo, dev.ev_hi, dev.cr_lo, dev.cr_hi,
-                    jnp.asarray(p32), jnp.asarray(s32),
-                )
-                elo, ehi, clo, chi = (np.asarray(x)[:g] for x in planes)
-                self.transfers["h2d_bytes"] += 2 * gb * 4
-                self.transfers["d2h_bytes"] += 4 * gb * 4
+            with SPANS.span("fs.store.merge.resolve") as sp:
+                if sp:
+                    sp.set(stale=t.host_stale)
+                part_e, slot_e, found = self._index_find(t, uids)
+                resolve.parts, resolve.slots = part_e, slot_e
+                if t.host_stale:
+                    # host mirror is behind device truth: O(batch) coord gather
+                    g = len(uids)
+                    gb = _bucket(g)
+                    p32 = np.zeros(gb, np.int32)
+                    s32 = np.zeros(gb, np.int32)
+                    p32[:g] = part_e
+                    s32[:g] = slot_e
+                    planes = merge_ops.gather_slot_ts(
+                        dev.ev_lo, dev.ev_hi, dev.cr_lo, dev.cr_hi,
+                        jnp.asarray(p32), jnp.asarray(s32),
+                    )
+                    elo, ehi, clo, chi = (np.asarray(x)[:g] for x in planes)
+                    self.transfers["h2d_bytes"] += 2 * gb * 4
+                    self.transfers["d2h_bytes"] += 4 * gb * 4
+                    return (
+                        lookup_ops.combine_i64(elo, ehi),
+                        lookup_ops.combine_i64(clo, chi),
+                        found,
+                    )
                 return (
-                    lookup_ops.combine_i64(elo, ehi),
-                    lookup_ops.combine_i64(clo, chi),
-                    found,
+                    t.event_ts[part_e, slot_e], t.creation_ts[part_e, slot_e], found
                 )
-            return t.event_ts[part_e, slot_e], t.creation_ts[part_e, slot_e], found
 
         plan = plan_online_batch(ids, event_ts, creation_ts, resolve)
         part_e, slot_e = resolve.parts, resolve.slots
@@ -806,62 +840,69 @@ class OnlineStore:
         TTL-expired rows), so a caller caching decoded rows can re-check TTL
         later without another store read.  Both engines return byte-identical
         triples."""
-        spec = self._specs[(name, version)]
-        t = self._tables[(name, version)]
-        ids = np.asarray(ids, np.int64)
-        b = len(ids)
-        d = t.values.shape[-1]
-        if b == 0:
-            return (
-                np.zeros((0, d), np.float32),
-                np.zeros(0, bool),
-                np.zeros(0, np.int64),
-            )
-        ttl = spec.materialization.online_ttl
-        if use_kernel:
-            dev = self._ensure_device(t)
-            q_lo, q_hi, part, pos = lookup_ops.route_queries(self.num_partitions, ids)
-            slots = np.asarray(
-                lookup_ops.lookup(
-                    dev.keys_lo, dev.keys_hi,
-                    jnp.asarray(q_lo), jnp.asarray(q_hi),
+        with SPANS.span("fs.store.lookup") as sp:
+            spec = self._specs[(name, version)]
+            t = self._tables[(name, version)]
+            ids = np.asarray(ids, np.int64)
+            b = len(ids)
+            d = t.values.shape[-1]
+            if sp:
+                sp.set(keys=b, rows=_bucket(b) if use_kernel and b else b)
+            if b == 0:
+                return (
+                    np.zeros((0, d), np.float32),
+                    np.zeros(0, bool),
+                    np.zeros(0, np.int64),
                 )
-            )
-            self.transfers["h2d_bytes"] += _nbytes(q_lo, q_hi)
-            self.transfers["d2h_bytes"] += _nbytes(slots)
-            got = slots[part, pos]
-            found = got >= 0
-            bb = _bucket(b)
-            p32 = np.zeros(bb, np.int32)
-            s32 = np.zeros(bb, np.int32)
-            p32[:b] = part
-            s32[:b] = np.maximum(got, 0)  # clamp misses; masked below
-            vals_d, crlo_d, crhi_d = lookup_ops.gather_rows(
-                dev.values, dev.cr_lo, dev.cr_hi,
-                jnp.asarray(p32), jnp.asarray(s32),
-            )
-            self.transfers["h2d_bytes"] += 2 * bb * 4
-            self.transfers["d2h_bytes"] += bb * (d * 4 + 8)
-            vals = np.array(vals_d)[:b]
-            vals[~found] = 0.0
-            cr = lookup_ops.combine_i64(
-                np.asarray(crlo_d)[:b], np.asarray(crhi_d)[:b]
-            )
+            ttl = spec.materialization.online_ttl
+            if use_kernel:
+                dev = self._ensure_device(t)
+                q_lo, q_hi, part, pos = lookup_ops.route_queries(
+                    self.num_partitions, ids
+                )
+                with SPANS.span("fs.store.lookup.scan"):
+                    slots = np.asarray(
+                        lookup_ops.lookup(
+                            dev.keys_lo, dev.keys_hi,
+                            jnp.asarray(q_lo), jnp.asarray(q_hi),
+                        )
+                    )
+                self.transfers["h2d_bytes"] += _nbytes(q_lo, q_hi)
+                self.transfers["d2h_bytes"] += _nbytes(slots)
+                got = slots[part, pos]
+                found = got >= 0
+                bb = _bucket(b)
+                p32 = np.zeros(bb, np.int32)
+                s32 = np.zeros(bb, np.int32)
+                p32[:b] = part
+                s32[:b] = np.maximum(got, 0)  # clamp misses; masked below
+                with SPANS.span("fs.store.lookup.gather"):
+                    vals_d, crlo_d, crhi_d = lookup_ops.gather_rows(
+                        dev.values, dev.cr_lo, dev.cr_hi,
+                        jnp.asarray(p32), jnp.asarray(s32),
+                    )
+                    vals = np.array(vals_d)[:b]
+                    cr_lo = np.asarray(crlo_d)[:b]
+                    cr_hi = np.asarray(crhi_d)[:b]
+                self.transfers["h2d_bytes"] += 2 * bb * 4
+                self.transfers["d2h_bytes"] += bb * (d * 4 + 8)
+                vals[~found] = 0.0
+                cr = lookup_ops.combine_i64(cr_lo, cr_hi)
+                if now is not None and ttl is not None:
+                    expired = found & (now - cr > ttl)
+                    found = found & ~expired
+                    vals[expired] = 0.0
+                return vals, found, np.where(found, cr, 0)
+            self._sync_host(t)
+            vals = np.zeros((b, d), np.float32)
+            found = np.zeros(b, bool)
+            p, s, hit = self._index_find(t, ids)
+            cr = t.creation_ts[p, s]
             if now is not None and ttl is not None:
-                expired = found & (now - cr > ttl)
-                found = found & ~expired
-                vals[expired] = 0.0
+                hit = hit & ~(now - cr > ttl)
+            found[hit] = True
+            vals[hit] = t.values[p[hit], s[hit]]
             return vals, found, np.where(found, cr, 0)
-        self._sync_host(t)
-        vals = np.zeros((b, d), np.float32)
-        found = np.zeros(b, bool)
-        p, s, hit = self._index_find(t, ids)
-        cr = t.creation_ts[p, s]
-        if now is not None and ttl is not None:
-            hit = hit & ~(now - cr > ttl)
-        found[hit] = True
-        vals[hit] = t.values[p[hit], s[hit]]
-        return vals, found, np.where(found, cr, 0)
 
     def get_record(
         self, name: str, version: int, id_columns: list[np.ndarray]

@@ -1946,7 +1946,6 @@ class GeoFeatureStore:
         vals, found = self.replicator.stores[serving].lookup(
             name, version, id_columns, now=self.fs.clock(), use_kernel=use_kernel
         )
-        self.fs.monitor.system.observe("geo/read_modeled_ms", ms)
         return vals, found, {"region": serving, "modeled_ms": ms}
 
     # -- failure handling --------------------------------------------------------
