@@ -15,7 +15,9 @@ Semantics reproduced exactly:
 Layout: the paper's storage-partitioning scheme applied to device memory —
 hash-partitioned (P, C) slot tables whose key planes are exactly what BOTH
 kernels (kernels/online_lookup for GETs, kernels/online_merge for writes)
-scan, plus (P, C, D) feature values.
+scan, plus (P, C, D) feature values.  On the device the value plane is
+(P, C, ``device_width(D)``), zero past column D, so that the gather and the
+merge need not relayout the whole plane on every call.
 
 Host-mirror / device-truth protocol
 -----------------------------------
@@ -84,6 +86,7 @@ merge with (spec, stats); the replication log subscribes there.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections import deque
 from typing import Optional
 
@@ -100,7 +103,13 @@ from repro.core.table import Table
 from repro.kernels.online_lookup import ops as lookup_ops
 from repro.kernels.online_merge import ops as merge_ops
 
-__all__ = ["DeviceTableState", "MergeStats", "OnlineStore", "o_batch_byte_budget"]
+__all__ = [
+    "DeviceTableState",
+    "MergeStats",
+    "OnlineStore",
+    "device_width",
+    "o_batch_byte_budget",
+]
 
 _I32_MAX = np.int32(np.iinfo(np.int32).max)
 
@@ -114,10 +123,39 @@ def o_batch_byte_budget(batch: int, record_bytes: int) -> int:
     return 64 * batch * record_bytes
 
 
+def device_width(d: int) -> int:
+    """Columns of the device value plane that holds ``d`` features.
+
+    The TPU compiler keeps a (P, C, d) float32 plane row-major only at some
+    widths.  At the others it stores the plane feature-major, and
+    ``gather_rows`` and ``merge_at_slots`` relayout all of it on every call
+    (tests/kernels/test_tpu_compile.py).  Widths 1-4, 8 and multiples of
+    128 are copy-free for both programs, so 5-7 round up to 8 and widths
+    above 56 to a multiple of 128: no more than the padded copy the
+    compiler made at those widths.  From 9 to 56 a multiple of 8 frees the
+    gather; the merge still copies the plane once there, since 128 columns
+    would cost up to 14x the bytes."""
+    if d <= 4:
+        return d
+    m = 8 if d <= 56 else 128
+    return (d + m - 1) // m * m
+
+
 # the ONE shape-bucketing rule (kernels/online_lookup/ops.pow2_bucket):
 # round batch lengths up to a power of two so the jitted device ops see a
 # bounded set of shapes instead of retracing per batch size
 _bucket = lookup_ops.pow2_bucket
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _put_partition(plane: jax.Array, p: jax.Array, block: jax.Array) -> jax.Array:
+    """Write one partition's (C, D) rows into the first D columns of the
+    donated (P, C, W) device plane."""
+    return jax.lax.dynamic_update_slice(plane, block[None], (p, 0, 0))
+
+
+def _pad_cols(spec: FeatureSetSpec) -> int:
+    return device_width(len(spec.features)) - len(spec.features)
 
 
 def _nbytes(*arrays) -> int:
@@ -191,7 +229,7 @@ class DeviceTableState:
     ev_hi: jax.Array
     cr_lo: jax.Array  # (P, C) int32 creation_ts planes
     cr_hi: jax.Array
-    values: jax.Array  # (P, C, D) float32
+    values: jax.Array  # (P, C, device_width(D)) float32, zero past D
 
     def planes(self) -> tuple[jax.Array, ...]:
         return (
@@ -304,6 +342,14 @@ class OnlineStore:
         if t.device is None:
             elo, ehi = lookup_ops.split_i64(t.event_ts)
             clo, chi = lookup_ops.split_i64(t.creation_ts)
+            # a partition at a time into a zeroed plane of the device width:
+            # no padded host copy, and waiting on each write keeps one block
+            # on the device beyond the plane, not all P
+            w = device_width(t.values.shape[-1])
+            values = jnp.zeros(t.values.shape[:-1] + (w,), jnp.float32)
+            for p, block in enumerate(t.values):
+                values = _put_partition(values, np.int32(p), jnp.asarray(block))
+                values.block_until_ready()
             t.device = DeviceTableState(
                 keys_lo=jnp.asarray(t.keys_lo),
                 keys_hi=jnp.asarray(t.keys_hi),
@@ -311,7 +357,7 @@ class OnlineStore:
                 ev_hi=jnp.asarray(ehi),
                 cr_lo=jnp.asarray(clo),
                 cr_hi=jnp.asarray(chi),
-                values=jnp.asarray(t.values),
+                values=values,
             )
             self.transfers["h2d_bytes"] += _nbytes(
                 t.keys_lo, t.keys_hi, elo, ehi, clo, chi, t.values
@@ -329,10 +375,12 @@ class OnlineStore:
         elo, ehi, clo, chi = (
             np.asarray(x) for x in (d.ev_lo, d.ev_hi, d.cr_lo, d.cr_hi)
         )
+        vals = np.asarray(d.values)
         t.event_ts = lookup_ops.combine_i64(elo, ehi)
         t.creation_ts = lookup_ops.combine_i64(clo, chi)
-        t.values = np.array(d.values)  # copy: mirror must stay writable
-        self.transfers["d2h_bytes"] += _nbytes(elo, ehi, clo, chi, t.values)
+        # copy: the mirror must stay writable, and holds no pad columns
+        t.values = np.array(vals[..., : t.values.shape[-1]])
+        self.transfers["d2h_bytes"] += _nbytes(elo, ehi, clo, chi, vals)
         self.transfers["host_syncs"] += 1
         t.host_stale = False
 
@@ -474,6 +522,8 @@ class OnlineStore:
                     overrides=stats.overrides,
                     noops=stats.noops,
                 )
+                if engine == "kernel":
+                    sp.set(pad_cols=_pad_cols(spec))
             return stats
 
     def merge_reduced(
@@ -533,6 +583,8 @@ class OnlineStore:
                     overrides=stats.overrides,
                     noops=stats.noops,
                 )
+                if engine == "kernel":
+                    sp.set(pad_cols=_pad_cols(spec))
             return stats
 
     @staticmethod
@@ -657,8 +709,9 @@ class OnlineStore:
             welo = np.zeros(gb, np.int32)
             wehi = np.zeros(gb, np.int32)
             welo[:g], wehi[:g] = lookup_ops.split_i64(plan.winner_ev)
-            wf = np.zeros((gb, wfeats.shape[1]), np.float32)
-            wf[:g] = wfeats
+            # pad columns stay zero in the winner rows, so in the plane too
+            wf = np.zeros((gb, dev.values.shape[-1]), np.float32)
+            wf[:g, : wfeats.shape[1]] = wfeats
             cr_planes = np.asarray(
                 np.concatenate(
                     lookup_ops.split_i64(np.asarray([creation_ts]))
@@ -857,6 +910,8 @@ class OnlineStore:
             ttl = spec.materialization.online_ttl
             if use_kernel:
                 dev = self._ensure_device(t)
+                if sp:
+                    sp.set(pad_cols=_pad_cols(spec))
                 q_lo, q_hi, part, pos = lookup_ops.route_queries(
                     self.num_partitions, ids
                 )
@@ -881,11 +936,11 @@ class OnlineStore:
                         dev.values, dev.cr_lo, dev.cr_hi,
                         jnp.asarray(p32), jnp.asarray(s32),
                     )
-                    vals = np.array(vals_d)[:b]
+                    vals = np.array(np.asarray(vals_d)[:b, :d])
                     cr_lo = np.asarray(crlo_d)[:b]
                     cr_hi = np.asarray(crhi_d)[:b]
                 self.transfers["h2d_bytes"] += 2 * bb * 4
-                self.transfers["d2h_bytes"] += bb * (d * 4 + 8)
+                self.transfers["d2h_bytes"] += bb * (dev.values.shape[-1] * 4 + 8)
                 vals[~found] = 0.0
                 cr = lookup_ops.combine_i64(cr_lo, cr_hi)
                 if now is not None and ttl is not None:

@@ -272,3 +272,80 @@ def test_transfer_ledger_counts_uploads_and_syncs():
     assert store.transfer_stats()["host_syncs"] == 1
     store.dump_all("fs", 1)  # mirror clean: no second pull
     assert store.transfer_stats()["host_syncs"] == 1
+
+
+# -- the lane-aligned device value plane --------------------------------------
+
+
+@pytest.mark.parametrize(
+    ("n_feats", "width"),
+    [(1, 1), (3, 3), (4, 4), (5, 8), (8, 8), (9, 16), (40, 40), (56, 56),
+     (57, 128), (128, 128), (129, 256), (250, 256), (300, 384)],
+)
+def test_device_width_rule(n_feats, width):
+    """Widths the TPU compiler keeps row-major stay; the others pad to the
+    next width that is copy-free (tests/kernels/test_tpu_compile.py)."""
+    from repro.core.online_store import device_width
+
+    assert device_width(n_feats) == width
+
+
+@pytest.mark.parametrize("n_feats", [130, 250])
+def test_padded_value_plane_matches_vector_engine(tmp_path, n_feats):
+    """At a width that is not a multiple of 128 the device plane is
+    (P, C, device_width(D)) with zero pad columns, and the kernel engine's
+    GET triples stay byte-identical to the vector engine's through inserts,
+    overrides, no-ops, a TTL expiry, a capacity doubling and a mirror sync."""
+    import jax
+
+    from repro.core.monitoring import SPANS
+    from repro.core.online_store import device_width
+
+    width = device_width(n_feats)
+    assert width == 256
+    spec = make_spec(ttl=100, n_feats=n_feats)
+    k = OnlineStore(num_partitions=2, initial_capacity=8, merge_engine="kernel")
+    v = OnlineStore(num_partitions=2, initial_capacity=8, merge_engine="vector")
+    rng = np.random.default_rng(10)
+    ids = np.arange(40, dtype=np.int64)
+
+    def same_gets(now, label):
+        got_k = k.lookup_encoded("fs", 1, ids, now=now)
+        got_v = v.lookup_encoded("fs", 1, ids, now=now, use_kernel=False)
+        for a, b in zip(got_k, got_v):
+            assert a.dtype == b.dtype and a.shape == b.shape, label
+            assert a.tobytes() == b.tobytes(), label
+
+    def plane_ok(label):
+        vals = np.asarray(k.device_state("fs", 1).values)
+        c = k._tables[spec.key].keys_lo.shape[1]
+        assert vals.shape == (2, c, width), label
+        assert not vals[..., n_feats:].any(), f"{label}: pad columns written"
+
+    # inserts, then overrides and no-ops (the same ids at earlier and later ts)
+    for i, creation in enumerate((1_000, 1_050, 1_060)):
+        f = make_frame(rng, 30, 20, 10 + 40 * i, n_feats=n_feats)
+        k.merge(spec, f, creation)
+        v.merge(spec, f, creation)
+        same_gets(creation + 5, f"merge {i}")
+    assert k.noops > 0 and k.overrides > 0
+    plane_ok("after merges")
+    same_gets(1_125, "1,000 cohort expired")  # TTL: older rows invisible
+    found = [k.lookup_encoded("fs", 1, ids, now=now)[1].sum() for now in (1_065, 1_125)]
+    assert found[1] < found[0]
+    # a capacity doubling: the kernel store syncs, drops and re-uploads
+    f = make_frame(rng, 60, 400, 200, n_feats=n_feats)
+    k.merge(spec, f, 1_100)
+    v.merge(spec, f, 1_100)
+    assert k._tables[spec.key].keys_lo.shape[1] > 8
+    plane_ok("after grow")
+    ids = np.concatenate([ids, np.unique(f["entity_id"])])
+    same_gets(1_110, "after grow")
+    # the host mirror carries no pad
+    k.sync_host_mirrors()
+    assert k._tables[spec.key].values.shape[-1] == n_feats
+    assert_online_identical(k, v, spec, "padded plane")
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        k.lookup_encoded("fs", 1, ids[:5])
+    (lookup,) = SPANS.spans("fs.store.lookup")
+    assert lookup.attrs["pad_cols"] == width - n_feats

@@ -202,6 +202,7 @@ def test_kernel_merge_on_stale_mirror_records_resolve(tmp_path):
         "inserts": stats.inserts,
         "overrides": stats.overrides,
         "noops": stats.noops,
+        "pad_cols": 0,
     }
     # the listeners ran inside the span
     ((heard, at),) = seen

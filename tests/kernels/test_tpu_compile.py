@@ -7,7 +7,11 @@ each kernel of the main path compiles here compiled (``interpret=False``),
 at the sizes ``chip_smoke.py`` runs: a 2^20-entity online table (16
 partitions x 65,536 slots, 8 features), 2^21-row merge batches, a
 6 x 2^20-row rolling window and offline history, a 65,536-row spine and
-4096-id GETs.
+4096-id GETs.  The resident value plane's programs also compile at the
+benchmark's table (16 x 2^18 slots) over the feature widths the store's
+``device_width`` rule gives, and must carry no plane-sized temporary: at
+a width the compiler stores feature-major, every call would first
+relayout the whole plane.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported, so every xdist worker collects the same tests and only
@@ -22,6 +26,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.online_store import device_width
 from repro.kernels.online_lookup.kernel import lookup_kernel_call
 from repro.kernels.online_lookup.ops import gather_rows
 from repro.kernels.online_merge.kernel import merge_kernel_call
@@ -37,6 +42,8 @@ ROLL_ROWS = 6 << 20  # the 6-hour transform window
 HISTORY_ROWS = 6 << 20
 SPINE = 65_536
 HBM_BYTES = 16 * 2**30  # one v5e chip
+BENCH_SLOTS = 1 << 18  # the benchmark's 3.9M records, 16 partitions
+SERVE_BATCH, UPDATE_BATCH = 4096, 1024
 
 
 @pytest.fixture(scope="module")
@@ -68,7 +75,9 @@ def _spec(sharding, shape, dtype=jnp.int32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _check(compiled, *, kernel: bool):
+def _check(compiled, *, kernel: bool, plane_bytes: int = 0):
+    """Fits one chip, has a Pallas kernel iff ``kernel``; given the value
+    plane's bytes, its temporaries stay under 1% of them (no relayout)."""
     mem = compiled.memory_analysis()
     used = (
         mem.argument_size_in_bytes
@@ -78,6 +87,10 @@ def _check(compiled, *, kernel: bool):
     )
     assert used < HBM_BYTES, used
     assert ("tpu_custom_call" in compiled.as_text()) == kernel
+    if plane_bytes:
+        assert mem.temp_size_in_bytes < plane_bytes / 100, (
+            mem.temp_size_in_bytes, plane_bytes
+        )
 
 
 def test_online_lookup_compiles(one_chip):
@@ -136,6 +149,9 @@ def test_rolling_sum_xla_compiles(one_chip):
 
 
 def test_merge_at_slots_compiles(one_chip):
+    """A whole materialization tick: the 2^21-row batch is larger than the
+    table, so its temporaries are batch-sized; the plane check is made at
+    serving batches in ``test_value_plane_programs_copy_free``."""
     plane = _spec(one_chip, (PARTS, SLOTS))
     batch = _spec(one_chip, (MERGE_BATCH,))
     compiled = merge_at_slots.lower(
@@ -157,4 +173,40 @@ def test_gather_rows_compiles(one_chip):
         _spec(one_chip, (PARTS, SLOTS, FEATS), jnp.float32),
         plane, plane, coords, coords,
     ).compile()
-    _check(compiled, kernel=False)
+    _check(compiled, kernel=False, plane_bytes=PARTS * SLOTS * FEATS * 4)
+
+
+# 3 and 8 (chip_smoke) keep their width, as do 40 and 128; 6 widens to 8,
+# 60 and 100 to 128, 250 (the benchmark's) to 256 and 300 to 384.  From 9
+# to 56 the merge still copies the plane once (see ``device_width``), so 40
+# is checked for the gather alone; so is 3, whose 50 MB plane is too small
+# for 1% of it to hold the merge's 1.2 MB of batch temporaries.
+_PLANE_CASES = [
+    (program, features)
+    for features in (3, 6, 8, 40, 60, 100, 128, 250, 300)
+    for program in ("gather_rows", "merge_at_slots")
+    if (program, features) not in {("merge_at_slots", 40), ("merge_at_slots", 3)}
+]
+
+
+@pytest.mark.parametrize(("program", "features"), _PLANE_CASES)
+def test_value_plane_programs_copy_free(one_chip, program, features):
+    width = device_width(features)
+    values = _spec(one_chip, (PARTS, BENCH_SLOTS, width), jnp.float32)
+    plane = _spec(one_chip, (PARTS, BENCH_SLOTS))
+    if program == "gather_rows":
+        coords = _spec(one_chip, (SERVE_BATCH,))
+        lowered = gather_rows.lower(values, plane, plane, coords, coords)
+    else:
+        batch = _spec(one_chip, (UPDATE_BATCH,))
+        lowered = merge_at_slots.lower(
+            plane, plane, plane, plane, plane, plane, values,
+            batch, batch, batch, batch,
+            _spec(one_chip, (UPDATE_BATCH,), jnp.bool_),
+            batch, batch,
+            _spec(one_chip, (2,)),
+            _spec(one_chip, (UPDATE_BATCH, width), jnp.float32),
+        )
+    _check(
+        lowered.compile(), kernel=False, plane_bytes=PARTS * BENCH_SLOTS * width * 4
+    )
