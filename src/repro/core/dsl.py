@@ -6,8 +6,17 @@ rolling window aggregation" — the query engine can optimize execution.  Our
 optimizer does exactly what the paper sketches ("optimize the aggregation
 based on join results"):
 
-  * the (entity, timestamp) sort and per-row window-start index are computed
-    ONCE and shared by every aggregation over the same window length;
+  * given the ``feature_window`` Algorithm 1 keeps (``context``), the plan
+    runs over the rows of the entities that have a row in it, and returns
+    the window's rows: a row's window holds rows of its own entity alone,
+    so the rest of the lookback cannot change a kept row's features;
+  * the (entity, timestamp) sort and the end of each row's timestamp tie
+    are computed ONCE for the plan, the per-row window-start index once per
+    window length, and shared by every aggregation over it;
+  * a row's window is every row of its entity with
+    ``ts - window < ts_j <= ts``: rows tied with it on (entity, ts) are in
+    it whatever their sorted order, so each aggregate of the contiguous
+    span ``[start, i]`` is read at the tie's last row;
   * aggregations over the same source column share the loaded column;
   * sum-family aggregations lower to the Pallas rolling-sum kernel
     (kernels/rolling_agg) — one masked MXU matmul per row block;
@@ -24,10 +33,10 @@ import hashlib
 import inspect
 from typing import Any, Callable, Sequence
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.assets import TransformProtocol
+from repro.core.keys import encode_keys
 from repro.core.table import Table
 from repro.kernels.rolling_agg import ops as rolling_ops
 
@@ -92,6 +101,9 @@ class DslTransform(TransformProtocol):
 
     # -- optimized execution -------------------------------------------------
     def __call__(self, source_df: Table, context: dict[str, Any]) -> Table:
+        kept = context.get("feature_window")
+        if kept is not None:
+            source_df = self._entities_in(source_df, kept)
         n = len(source_df)
         # Shared sort by (entity..., ts): done once for the whole plan.
         sort_cols = (*self.entity_cols, self.timestamp_col)
@@ -99,6 +111,9 @@ class DslTransform(TransformProtocol):
         sorted_df = source_df.take(order)
         ts = sorted_df[self.timestamp_col].astype(np.int64)
         seg = self._segment_ids(sorted_df)
+        # A row's window ends at the last row of its (entity, ts) tie, so
+        # each aggregate is read there.
+        tie_end = _tie_ends(seg, ts)
 
         # Shared window-start indices per distinct window length.
         starts_by_window: dict[int, np.ndarray] = {}
@@ -109,6 +124,7 @@ class DslTransform(TransformProtocol):
                     if n
                     else np.zeros((0,), np.int32)
                 )
+        counts = {w: tie_end + 1 - st for w, st in starts_by_window.items()}
 
         # Group sum/mean aggs that share a window so one kernel launch
         # covers all their source columns (columns stacked on the lane dim).
@@ -123,18 +139,15 @@ class DslTransform(TransformProtocol):
         for window, group in kernel_groups.items():
             cols = sorted(set(a.source_col for a in group))
             mat = np.stack([sorted_df[c].astype(np.float32) for c in cols], axis=1)
-            sums = np.asarray(
-                rolling_ops.rolling_agg(
-                    jnp.asarray(mat), starts_by_window[window], "sum",
-                    backend="pallas" if self.use_kernel else "xla",
-                    monitor=context.get("monitor"),
-                )
-            )
-            counts = np.arange(n) + 1 - starts_by_window[window]
+            sums = rolling_ops.rolling_agg(
+                mat, starts_by_window[window], "sum",
+                backend="pallas" if self.use_kernel else "xla",
+                monitor=context.get("monitor"),
+            )[tie_end]
             for a in group:
                 col = sums[:, cols.index(a.source_col)]
                 if a.agg == "mean":
-                    col = col / np.maximum(counts, 1)
+                    col = col / np.maximum(counts[window], 1)
                 out_cols[a.output] = col.astype(np.float32)
 
         for a in self.aggs:
@@ -142,18 +155,26 @@ class DslTransform(TransformProtocol):
                 continue
             starts = starts_by_window[a.window]
             if a.agg == "count":
-                out_cols[a.output] = (np.arange(n) + 1 - starts).astype(np.float32)
+                out_cols[a.output] = counts[a.window].astype(np.float32)
             elif n == 0:
                 out_cols[a.output] = np.zeros((0,), np.float32)
             else:
                 vals = sorted_df[a.source_col].astype(np.float32)[:, None]
-                out_cols[a.output] = np.asarray(
-                    rolling_ops.rolling_agg(
-                        jnp.asarray(vals), starts, a.agg
-                    )
-                )[:, 0].astype(np.float32)
+                out_cols[a.output] = rolling_ops.rolling_agg(
+                    vals, starts, a.agg
+                )[tie_end, 0].astype(np.float32)
 
-        return Table(out_cols)
+        out = Table(out_cols)
+        if kept is None:
+            return out
+        return out.filter_time_range(self.timestamp_col, kept.start, kept.end)
+
+    def _entities_in(self, df: Table, window) -> Table:
+        """The rows of ``df`` whose entity has a row in ``window``."""
+        ts = df[self.timestamp_col]
+        key = encode_keys([df[c] for c in self.entity_cols])
+        in_window = key[(ts >= window.start) & (ts < window.end)]
+        return df.filter(np.isin(key, in_window))
 
     def _segment_ids(self, sorted_df: Table) -> np.ndarray:
         n = len(sorted_df)
@@ -164,6 +185,16 @@ class DslTransform(TransformProtocol):
             col = sorted_df[c]
             change[1:] |= col[1:] != col[:-1]
         return np.cumsum(change).astype(np.int64)
+
+
+def _tie_ends(seg: np.ndarray, ts: np.ndarray) -> np.ndarray:
+    """For rows sorted by (segment, ts): the index of the last row of each
+    row's (segment, ts) tie (its own index when nothing ties with it)."""
+    n = len(ts)
+    last = np.ones(n, bool)
+    last[:-1] = (seg[1:] != seg[:-1]) | (ts[1:] != ts[:-1])
+    # each row's tie is the number of tie ends before it
+    return np.flatnonzero(last)[np.cumsum(last) - last]
 
 
 class UDFTransform(TransformProtocol):

@@ -9,6 +9,9 @@ online latest-wins) guarantees retries converge.
 ``FaultInjector`` lets tests and benchmarks break the pipeline at the exact
 seams the paper discusses: after compute, after the offline merge, after the
 online merge.
+
+``run_job`` is the span ``fs.materialize.job`` (``monitoring.SPANS``, only
+under a profiler session) with ``job_id`` and ``rows_out`` (rows written).
 """
 
 from __future__ import annotations
@@ -17,7 +20,7 @@ import dataclasses
 from typing import Callable, Optional
 
 from repro.core.assets import FeatureSetSpec
-from repro.core.monitoring import HealthMonitor
+from repro.core.monitoring import SPANS, HealthMonitor
 from repro.core.offline_store import OfflineStore
 from repro.core.online_store import OnlineStore
 from repro.core.scheduler import MaterializationJob
@@ -103,56 +106,61 @@ class Materializer:
         """Execute one job; raises on (injected or real) failure.  The paper's
         merge order — offline first, then online — is fixed, which is one of
         the §4.5.4 reasons the stores are only EVENTUALLY consistent."""
-        self.faults.check("before_compute")
-        frame = compute_feature_window(
-            spec, source, job.window, {"monitor": self.monitor}
-        )
-        self.faults.check("after_compute")
-
-        creation_ts = int(self.clock())
-        offline_done = online_done = False
-        offline_stats = None
-        if spec.materialization.offline_enabled:
-            # OfflineStore normalizes "kernel" (online-only) to its vector path
-            stats = self.offline.merge_with_stats(
-                spec, frame, creation_ts, engine=self.merge_engine
+        with SPANS.span("fs.materialize.job", job_id=job.job_id) as sp:
+            self.faults.check("before_compute")
+            frame = compute_feature_window(
+                spec, source, job.window, {"monitor": self.monitor}
             )
-            offline_stats = {
-                "inserted": stats["inserted"],
-                "deduped": stats["deduped"],
-                # seq the geo-replication log assigned this batch's offline
-                # plane (annotated by the GeoReplicator's offline merge
-                # listener; None when unattached or fully deduped)
-                "replication_seq": stats.get("replication_seq"),
-            }
-            offline_done = True
-        self.faults.check("between_merges")
-        online_stats = None
-        if spec.materialization.online_enabled:
-            stats = self.online.merge(
-                spec, frame, creation_ts, engine=self.merge_engine
-            )
-            online_stats = {
-                "inserts": stats["inserts"],
-                "overrides": stats["overrides"],
-                "noops": stats["noops"],
-                "touched_slots": len(stats["touched_slots"]),
-                # seq the geo-replication log assigned this batch (annotated
-                # by the GeoReplicator's merge listener; None when no
-                # replication is attached or the batch was all no-ops)
-                "replication_seq": stats.get("replication_seq"),
-            }
-            online_done = True
-        self.faults.check("after_merges")
+            if sp:
+                sp.set(rows_out=len(frame))
+            self.faults.check("after_compute")
 
-        outcome = MaterializationOutcome(
-            job.job_id,
-            len(frame),
-            offline_done,
-            online_done,
-            creation_ts,
-            online_stats=online_stats,
-            offline_stats=offline_stats,
-        )
-        self.outcomes.append(outcome)
-        return outcome
+            creation_ts = int(self.clock())
+            offline_done = online_done = False
+            offline_stats = None
+            if spec.materialization.offline_enabled:
+                # OfflineStore normalizes "kernel" (online-only) to its
+                # vector path
+                stats = self.offline.merge_with_stats(
+                    spec, frame, creation_ts, engine=self.merge_engine
+                )
+                offline_stats = {
+                    "inserted": stats["inserted"],
+                    "deduped": stats["deduped"],
+                    # seq the geo-replication log assigned this batch's
+                    # offline plane (annotated by the GeoReplicator's offline
+                    # merge listener; None when unattached or fully deduped)
+                    "replication_seq": stats.get("replication_seq"),
+                }
+                offline_done = True
+            self.faults.check("between_merges")
+            online_stats = None
+            if spec.materialization.online_enabled:
+                stats = self.online.merge(
+                    spec, frame, creation_ts, engine=self.merge_engine
+                )
+                online_stats = {
+                    "inserts": stats["inserts"],
+                    "overrides": stats["overrides"],
+                    "noops": stats["noops"],
+                    "touched_slots": len(stats["touched_slots"]),
+                    # seq the geo-replication log assigned this batch
+                    # (annotated by the GeoReplicator's merge listener; None
+                    # when no replication is attached or the batch was all
+                    # no-ops)
+                    "replication_seq": stats.get("replication_seq"),
+                }
+                online_done = True
+            self.faults.check("after_merges")
+
+            outcome = MaterializationOutcome(
+                job.job_id,
+                len(frame),
+                offline_done,
+                online_done,
+                creation_ts,
+                online_stats=online_stats,
+                offline_stats=offline_stats,
+            )
+            self.outcomes.append(outcome)
+            return outcome

@@ -47,6 +47,7 @@ import numpy as np
 from repro.core.assets import FeatureSetSpec
 from repro.core.keys import encode_full_keys, encode_keys
 from repro.core.merge_engine import merge_sorted
+from repro.core.monitoring import SPANS
 from repro.core.table import Table, concat_tables
 from repro.kernels.online_lookup.ops import partition_of
 
@@ -177,7 +178,27 @@ class OfflineStore:
         inserted_columns``, arrival order) — the reduced form
         geo-replication ships — and the listeners fire with (spec, stats),
         mirroring ``OnlineStore.merge``; a replication listener annotates
-        ``stats["replication_seq"]``."""
+        ``stats["replication_seq"]``.
+
+        The whole call is the span ``fs.offline.merge`` (``rows``,
+        ``inserted``, ``deduped``; only under a profiler session)."""
+        with SPANS.span("fs.offline.merge") as sp:
+            stats = self._merge_stats(spec, frame, creation_ts, engine)
+            if sp:
+                sp.set(
+                    rows=len(frame),
+                    inserted=stats["inserted"],
+                    deduped=stats["deduped"],
+                )
+            return stats
+
+    def _merge_stats(
+        self,
+        spec: FeatureSetSpec,
+        frame: Table,
+        creation_ts: int,
+        engine: Optional[str],
+    ) -> dict:
         engine = self._normalize_engine(engine) if engine else self.merge_engine
         self.register(spec)
         n = len(frame)

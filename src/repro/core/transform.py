@@ -7,6 +7,10 @@
 
 The same flow is used by materialization jobs (incremental and backfill) and
 by on-the-fly offline joins of non-materialized feature sets (§4.2).
+
+Spans (``monitoring.SPANS``, recorded only under a profiler session):
+``fs.materialize.read`` around the source read (``rows``) and
+``fs.materialize.transform`` around the transform (``rows`` in).
 """
 
 from __future__ import annotations
@@ -17,6 +21,7 @@ from typing import Any, Protocol
 import numpy as np
 
 from repro.core.assets import FeatureSetSpec, validate_feature_frame
+from repro.core.monitoring import SPANS
 from repro.core.table import Table
 
 __all__ = ["SourceProtocol", "FeatureWindow", "compute_feature_window"]
@@ -67,9 +72,15 @@ def compute_feature_window(
     ctx.setdefault("feature_window", window)
 
     source_start = window.start - spec.source_lookback
-    df1 = source.read(source_start, window.end)
+    with SPANS.span("fs.materialize.read") as sp:
+        df1 = source.read(source_start, window.end)
+        if sp:
+            sp.set(rows=len(df1))
 
-    df2 = spec.transform(df1, ctx)
+    with SPANS.span("fs.materialize.transform") as sp:
+        if sp:
+            sp.set(rows=len(df1))
+        df2 = spec.transform(df1, ctx)
     df2 = validate_feature_frame(spec, df2)
 
     ts = df2[spec.timestamp_col].astype(np.int64)

@@ -1,10 +1,12 @@
 """jit'd public wrapper around the rolling-window aggregation kernel.
 
 Handles everything the raw kernel does not: the transposed (F, N) layout
-with features padded to 8 sublanes, row padding to block multiples, span
-bucketing (the kernel needs a static history depth >= the maximum window
-row-span), the VMEM bound on that depth, and the derived aggregations
-(count is closed-form; mean = sum / count; min/max use an XLA
+with features padded to 8 sublanes, row padding to block multiples, row
+bucketing (``rolling_agg`` pads N on the host to ``row_bucket(N)``, so a
+stream of jobs whose row counts differ runs a few compiled programs, not
+one per N), span bucketing (the kernel needs a static history depth >= the
+maximum window row-span), the VMEM bound on that depth, and the derived
+aggregations (count is closed-form; mean = sum / count; min/max use an XLA
 doubling formulation — the windowed-sum matmul does not apply to them).
 """
 
@@ -24,6 +26,7 @@ __all__ = [
     "rolling_extreme",
     "rolling_sum",
     "rolling_sum_xla",
+    "row_bucket",
     "window_starts",
 ]
 
@@ -31,6 +34,13 @@ _LANE = 128
 _SUBLANE = 8
 _DEFAULT_BLOCK = 256
 _SCAN_BLOCK = 1024  # rows per block of rolling_sum_xla's two-level scan
+# The smallest row bucket: a program over this many float32 rows moves
+# 64 KiB a column, so its time is its launch, and calls whose row counts
+# wander below it (a materialization job's entities) share one program.
+_MIN_ROWS = 16_384
+# Doubling levels below which min/max never go: spans up to 127 rows share
+# one program (a level costs an elementwise pass; the gathers cost the rest).
+_MIN_LEVELS = 7
 
 
 def _round_up(x: int, m: int) -> int:
@@ -42,9 +52,14 @@ def window_starts(
 ) -> np.ndarray:
     """Host-side window-start computation (rows sorted by (segment, ts)).
 
-    Window semantics: row j is in row i's window iff same segment and
-    ``ts_i - window < ts_j <= ts_i``.  Uses a composite monotone key so one
-    global vectorized searchsorted handles every segment at once.
+    ``starts[i]`` is the first row j of row i's segment with
+    ``ts_j > ts_i - window``: where row i's window begins.  Rows tied with
+    row i on (segment, ts) share its start.  Where the window ends is the
+    caller's: the kernels sum the contiguous span ``[starts[i], i]``, and
+    the DSL takes each row's value at the last row of its tie, so that its
+    window is every row with ``ts_i - window < ts_j <= ts_i``.  Uses a
+    composite monotone key so one global vectorized searchsorted handles
+    every segment at once.
     """
     segment_ids = np.asarray(segment_ids, dtype=np.int64)
     timestamps = np.asarray(timestamps, dtype=np.int64)
@@ -59,6 +74,19 @@ def window_starts(
     q = segment_ids * span + np.maximum(rebased - window, -1)
     starts = np.searchsorted(key, q, side="right")
     return starts.astype(np.int32)
+
+
+def row_bucket(n: int) -> int:
+    """Rows the rolling programs are compiled for, given ``n``: ``n``
+    rounded up to eight steps per power of two (at least ``_MIN_ROWS``), so
+    the padding is under 12.5% of ``n`` and a stream of jobs whose row
+    counts wander within a few percent runs one compiled program.  Not the
+    online path's ``pow2_bucket``: these programs' work is linear in the
+    padded rows, and a power of two pads a lookback of ~1.1M rows by 89%."""
+    if n <= _MIN_ROWS:
+        return _MIN_ROWS
+    step = 1 << ((n - 1).bit_length() - 4)
+    return _round_up(n, step)
 
 
 def _vmem_working_set(hist: int, block_rows: int, feat: int) -> int:
@@ -205,58 +233,67 @@ def _pick_hist(max_span: int) -> int:
 
 
 def rolling_agg(
-    values: jnp.ndarray,
+    values,
     starts: np.ndarray,
     agg: str,
     *,
     block_rows: int = _DEFAULT_BLOCK,
     backend: str = "pallas",
     monitor=None,
-) -> jnp.ndarray:
-    """Public entry used by the DSL executor.  ``starts`` must be host-side
-    (numpy) — the DSL computes it from store-resident timestamps — which lets
-    us pick the static history bucket and validate spans eagerly.
+) -> np.ndarray:
+    """Public entry used by the DSL executor: ``out[i]`` aggregates the rows
+    ``[starts[i], i]``.  ``starts`` must be host-side (numpy) — the DSL
+    computes it from store-resident timestamps — which lets us pick the
+    static history bucket and validate spans eagerly.
+
+    The rows are padded on the host to ``row_bucket(N)`` (pad rows: value
+    0, start = their own index, so no real row's window reaches them), the
+    history depth is a power of two, the min/max doubling levels the span's
+    bit length (at least ``_MIN_LEVELS``), and
+    the result comes back to the host before the padding is cut off: a
+    call compiles a new program only for a new bucket.  Returns a float32
+    (N, F) numpy array.
 
     backend: 'pallas' (the kernel; compiled on TPU, interpreted elsewhere)
     or 'xla' (the cumsum formulation).  A 'pallas' sum whose spans are
     deeper than ``max_hist`` allows takes the XLA path too, and reports it
     through ``monitor.record_kernel_fallback`` when a monitor is given."""
     starts = np.asarray(starts)
-    n = values.shape[0]
+    values = np.asarray(values)
+    n, feat = values.shape
     if n == 0:
-        return jnp.zeros((0, values.shape[1]), jnp.float32)
+        return np.zeros((0, feat), np.float32)
     spans = np.arange(n) + 1 - starts
     if (spans <= 0).any():
         raise ValueError("window starts must satisfy starts[i] <= i")
     max_span = int(spans.max())
 
     if agg == "count":
-        cnt = jnp.asarray(spans, dtype=jnp.float32)
-        return jnp.broadcast_to(cnt[:, None], values.shape).astype(jnp.float32)
+        return np.broadcast_to(spans.astype(np.float32)[:, None], (n, feat)).copy()
+    if agg not in ("sum", "mean", "min", "max"):
+        raise ValueError(f"unknown agg {agg!r}")
 
-    if agg in ("sum", "mean"):
-        hist = _pick_hist(max_span)
-        deep = hist > max_hist(values.shape[1], block_rows)
-        if backend == "pallas" and deep and monitor is not None:
-            monitor.record_kernel_fallback("rolling_agg")
-        if backend == "xla" or deep:
-            s = rolling_sum_xla(values, jnp.asarray(starts, jnp.int32))
-        else:
-            s = rolling_sum(
-                values,
-                jnp.asarray(starts, dtype=jnp.int32),
-                block_rows=block_rows,
-                hist=hist,
-            )
-        if agg == "sum":
-            return s
-        cnt = jnp.asarray(spans, dtype=jnp.float32)[:, None]
-        return s / jnp.maximum(cnt, 1.0)
+    hist = _pick_hist(max_span)
+    nb = row_bucket(n)
+    vals = np.zeros((nb, feat), np.float32)
+    vals[:n] = values
+    starts_p = np.arange(nb, dtype=np.int32)
+    starts_p[:n] = starts
+    vals, starts_p = jnp.asarray(vals), jnp.asarray(starts_p)
 
     if agg in ("min", "max"):
-        return rolling_extreme(
-            values, jnp.asarray(starts, jnp.int32),
-            levels=max_span.bit_length(), agg=agg,
-        )
+        levels = max(max_span.bit_length(), _MIN_LEVELS)
+        out = rolling_extreme(vals, starts_p, levels=levels, agg=agg)
+        return np.asarray(out)[:n]
 
-    raise ValueError(f"unknown agg {agg!r}")
+    deep = hist > max_hist(feat, block_rows)
+    if backend == "pallas" and deep and monitor is not None:
+        monitor.record_kernel_fallback("rolling_agg")
+    if backend == "xla" or deep:
+        s = rolling_sum_xla(vals, starts_p)
+    else:
+        s = rolling_sum(vals, starts_p, block_rows=block_rows, hist=hist)
+    s = np.asarray(s)[:n]
+    if agg == "sum":
+        return s
+    return s / np.maximum(spans, 1).astype(np.float32)[:, None]

@@ -1,8 +1,11 @@
 """Pure-jnp oracle for rolling-window aggregation.
 
-``out[i] = agg(values[starts[i] : i+1])`` — the window is a contiguous row
-span ending at row ``i`` (rows are sorted by (entity, timestamp) upstream; the
-DSL layer computes ``starts`` so windows never cross entity boundaries).
+``out[i] = agg(values[starts[i] : i+1])`` — the contiguous row span from
+``starts[i]`` through row ``i``, the kernels' contract.  Rows are sorted by
+(entity, timestamp) upstream, and the DSL computes ``starts`` so that no span
+crosses an entity boundary; it reads row ``i``'s feature at the last row of
+its (entity, timestamp) tie, so rows that tie with ``i`` and sort after it are
+in its window there, not in this span.
 """
 
 from __future__ import annotations

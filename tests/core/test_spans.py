@@ -1,7 +1,8 @@
 """The in-program span recorder (``monitoring.SPANS``): it records only
 inside a JAX profiler session, keeps parents, roots and self time, bounds
 its buffer, and starts afresh with each session; and the spans the serving
-front and the online store put at their layer boundaries."""
+front, the online store, the materializer and the offline store put at
+their layer boundaries."""
 
 import contextlib
 import itertools
@@ -12,10 +13,16 @@ import numpy as np
 import pytest
 
 from repro.core import monitoring
+from repro.core.assets import Entity, Feature, FeatureSetSpec, MaterializationSettings
+from repro.core.dsl import DslTransform, RollingAgg
+from repro.core.featurestore import FeatureStore
 from repro.core.monitoring import SPANS, HealthMonitor, SpanRecorder
 from repro.core.online_store import OnlineStore
 from repro.core.serving import ServingConfig, ServingFront
+from repro.data.sources import SyntheticEventSource
 from tests.core.test_serving import make_frame, make_spec
+
+HOUR = 3_600_000
 
 
 @contextlib.contextmanager
@@ -231,6 +238,7 @@ def test_off_path_records_nothing_and_touches_only_is_enabled(monkeypatch):
 
     store, spec, rng = _kernel_store()
     front = ServingFront(store, config=ServingConfig(cache_capacity=8))
+    fs = _materializing_store()
     kept, dropped = SPANS.spans(), SPANS.dropped
     monkeypatch.setattr(monitoring, "TraceAnnotation", Off)
     monkeypatch.setattr(monitoring, "time", NoClock())
@@ -241,6 +249,63 @@ def test_off_path_records_nothing_and_touches_only_is_enabled(monkeypatch):
         spec, np.arange(3, dtype=np.int64), np.full(3, 95, np.int64),
         np.zeros((3, 2), np.float32), 1_070,
     )
+    calls = Off.calls
+    assert fs.tick(2 * HOUR)["succeeded"] == 2
+    assert Off.calls - calls == 2 * 6  # job, read, transform, both merges, resolve
     assert SPANS.span("fs.x") is SPANS.span("fs.y")
     assert Off.calls >= 6  # dispatch, lookup, scan, gather, merges, resolves
     assert SPANS.spans() == kept and SPANS.dropped == dropped
+
+
+def _materializing_store():
+    """A kernel-engine store with one DSL feature set over two window
+    lengths, written to both stores every hour."""
+    fs = FeatureStore("spans", online_partitions=4, merge_engine="kernel")
+    fs.register_source(SyntheticEventSource("tx", num_entities=20,
+                                            events_per_bucket=60))
+    aggs = [RollingAgg("s1", "amount", HOUR, "sum"),
+            RollingAgg("m3", "quantity", 3 * HOUR, "max")]
+    fs.create_feature_set(FeatureSetSpec(
+        name="act", version=1, entity=Entity("customer", ("entity_id",)),
+        features=(Feature("s1"), Feature("m3")), source_name="tx",
+        transform=DslTransform("entity_id", "ts", aggs),
+        timestamp_col="ts", source_lookback=3 * HOUR,
+        materialization=MaterializationSettings(
+            offline_enabled=True, online_enabled=True, schedule_interval=HOUR
+        ),
+    ))
+    return fs
+
+
+def test_materialize_spans_once_per_job(tmp_path):
+    fs = _materializing_store()
+    kept = SPANS.spans()
+    assert fs.tick(4 * HOUR)["succeeded"] == 4  # outside a session
+    assert SPANS.spans() == kept
+    with profiled(tmp_path):
+        assert fs.tick(6 * HOUR)["succeeded"] == 2
+    jobs = SPANS.spans("fs.materialize.job")
+    assert [j.attrs["job_id"] for j in jobs] == [5, 6]
+    by_id = {s.id: s for s in SPANS.spans()}
+    for name in ("fs.materialize.read", "fs.materialize.transform",
+                 "fs.offline.merge", "fs.store.merge"):
+        found = SPANS.spans(name)
+        assert [by_id[s.parent].name for s in found] == ["fs.materialize.job"] * 2
+        assert [s.root for s in found] == [j.id for j in jobs]
+    outcomes = fs.materializer.outcomes[-2:]
+    for job, out in zip(jobs, outcomes):
+        (read,) = [s for s in SPANS.spans("fs.materialize.read") if s.parent == job.id]
+        (dsl,) = [s for s in SPANS.spans("fs.materialize.transform")
+                  if s.parent == job.id]
+        (off,) = [s for s in SPANS.spans("fs.offline.merge") if s.parent == job.id]
+        (onl,) = [s for s in SPANS.spans("fs.store.merge") if s.parent == job.id]
+        assert set(job.attrs) == {"job_id", "rows_out"}
+        # four hours of 60 events: the lookback and the job's own hour
+        assert read.attrs == dsl.attrs == {"rows": 240}
+        assert job.attrs["rows_out"] == out.rows == onl.attrs["rows"] == 60
+        assert off.attrs == {
+            "rows": 60,
+            "inserted": out.offline_stats["inserted"],
+            "deduped": out.offline_stats["deduped"],
+        }
+        assert off.attrs["inserted"] + off.attrs["deduped"] == 60

@@ -4,6 +4,7 @@ Pallas runs in interpret mode here: the mode follows the backend (CPU here;
 the kernels compile on a TPU).
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -16,6 +17,7 @@ from repro.kernels.rolling_agg.ops import (
     rolling_agg,
     rolling_sum,
     rolling_sum_xla,
+    row_bucket,
     window_starts,
 )
 
@@ -143,6 +145,46 @@ def test_rolling_sum_xla_long_column_is_exact():
     prefix = np.concatenate([np.zeros((1, 2)), np.cumsum(vals, 0, np.float64)])
     want = prefix[1:] - prefix[starts]
     np.testing.assert_allclose(got, want, rtol=1e-6)
+
+
+@pytest.mark.parametrize("n", [1, 16_384, 16_385, 32_768, 32_769, 100_000, 1_110_612])
+def test_row_bucket_pads_under_an_eighth(n):
+    b = row_bucket(n)
+    assert b >= max(n, 16_384) and b % 128 == 0
+    if n > 16_384:
+        assert (b - n) / n < 0.125
+
+
+def test_rolling_agg_lowers_nothing_new_within_a_row_bucket():
+    """Calls whose row counts differ but share a bucket run the programs
+    the first call compiled: no jaxpr is lowered again."""
+    lowered = []
+
+    def on_duration(event, duration, **_):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            lowered.append(event)
+
+    rng = np.random.default_rng(11)
+    cases = []
+    for n in (2100, 9000, 16_000):  # all in the smallest bucket
+        vals, starts, _, _ = _random_case(rng, n, 2, 40, 60)
+        cases.append((vals, starts))
+    aggs = ("sum", "mean", "max", "min")
+    for agg in aggs:
+        for backend in ("pallas", "xla"):
+            rolling_agg(cases[0][0], cases[0][1], agg, backend=backend)
+    want = {(i, agg): R.rolling_agg_ref(jnp.asarray(v), jnp.asarray(st), agg)
+            for i, (v, st) in enumerate(cases) for agg in aggs}
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        got = {(i, agg, backend): rolling_agg(v, st, agg, backend=backend)
+               for i, (v, st) in enumerate(cases) if i
+               for agg in aggs for backend in ("pallas", "xla")}
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert lowered == []
+    for (i, agg, _), out in got.items():
+        np.testing.assert_allclose(out, want[i, agg], rtol=1e-5, atol=1e-4)
 
 
 # ---------------------------------------------------------------------------
