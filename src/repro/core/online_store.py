@@ -13,11 +13,12 @@ Semantics reproduced exactly:
     per-partition free lists so partitions stay bounded under TTL churn.
 
 Layout: the paper's storage-partitioning scheme applied to device memory —
-hash-partitioned (P, C) slot tables whose key planes are exactly what BOTH
-kernels (kernels/online_lookup for GETs, kernels/online_merge for writes)
-scan, plus (P, C, D) feature values.  On the device the value plane is
-(P, C, ``device_width(D)``), zero past column D, so that the gather and the
-merge need not relayout the whole plane on every call.
+hash-partitioned (P, C) slot tables of key planes, timestamp planes and
+(P, C, D) feature values, plus a sorted key index that maps a key to its
+(part, slot); GETs search it (kernels/online_lookup), writes resolve
+against it on the host (kernels/online_merge).  On the device the value
+plane is (P, C, ``device_width(D)``), zero past column D, so that the
+gather and the merge need not relayout the whole plane on every call.
 
 Host-mirror / device-truth protocol
 -----------------------------------
@@ -29,30 +30,34 @@ the source of truth between kernel merges/lookups:
     Algorithm-2 tallies from the plan), then applies it with ONE donated
     compare-and-update scatter (``merge_at_slots``) that rewrites the planes
     in their existing device buffers — traffic is O(batch), never O(P·C·D);
-  * a kernel GET runs the Pallas lookup kernel against the resident key
-    planes and gathers feature rows + creation_ts planes at the resolved
-    slots on device (``gather_rows``) — again O(batch) both ways, with TTL
-    expiry computed from device truth, not the host mirror;
+  * a kernel GET binary-searches a device copy of the sorted key index
+    (``DeviceKeyIndex``) and gathers feature rows + creation_ts planes at
+    the resolved slots on device (``gather_rows``), with no host copy
+    between the two and one wait at the end — O(batch) both ways, with TTL
+    expiry computed from device truth, not the host mirror.  A kernel
+    merge's inserts join the host index at once and are queued for the
+    device copy, which the next kernel GET merges in, uploading only them;
   * the host numpy planes become a LAZY MIRROR: ``host_stale`` is set by
     every kernel merge, and any host-side consumer (``dump_all``,
     ``get_record``, ``sweep``, host-path lookups, the ``vector``/``loop``
     engines, ``sync_host_mirrors``) first syncs the mirror — one O(P·C·D)
     pull, amortized across arbitrarily many device-side operations;
   * host MUTATIONS (vector/loop merges, ``sweep``, ``_grow``) sync first and
-    then DROP the device state (host becomes sole truth again); the next
-    kernel operation re-uploads lazily.  Slot assignment, the sorted key
-    index, ``keys_full``, and ``fill`` always live on host (inserts resolve
-    there), and inserted keys are scattered into the device planes inside
-    the same donated update.
+    then DROP the device state, index included (host becomes sole truth
+    again); the next kernel operation re-uploads lazily.  Slot assignment,
+    the sorted key index, ``keys_full``, and ``fill`` always live on host
+    (inserts resolve there), and inserted keys are scattered into the device
+    planes inside the same donated update.
 
 ``transfers`` tallies every host<->device byte the store moves, so tests and
 benchmarks can assert the steady-state cycle is O(batch).
 
 Under a JAX profiler session the read and write paths record spans
 (``monitoring.SPANS``): ``fs.store.lookup`` around each ``lookup_encoded``,
-with ``fs.store.lookup.scan`` (query upload through the slot matrix's host
-copy) and ``fs.store.lookup.gather`` (``gather_rows`` through the rows'
-host copy) inside it on the kernel path; ``fs.store.merge`` around each
+with ``fs.store.lookup.scan`` (query upload, any merge of queued index
+entries, and the search's dispatch; attribute ``index_merged``) and
+``fs.store.lookup.gather`` (``gather_rows`` through the host copies)
+inside it on the kernel path; ``fs.store.merge`` around each
 ``merge``/``merge_reduced``, listeners included, with
 ``fs.store.merge.resolve`` around the index find and, on a stale mirror,
 the device gather of the old timestamps.  They end at host copies the
@@ -112,6 +117,9 @@ __all__ = [
 ]
 
 _I32_MAX = np.int32(np.iinfo(np.int32).max)
+# a device key index entry past the live keys: key int64 max (lo, hi),
+# part -1, slot -1
+_INDEX_PAD = np.array([-1, _I32_MAX, -1, -1], np.int32)
 
 
 def o_batch_byte_budget(batch: int, record_bytes: int) -> int:
@@ -218,10 +226,30 @@ class MergeStats:
 
 
 @dataclasses.dataclass
+class DeviceKeyIndex:
+    """The host's sorted key index on the device: four (P·C,) int32 planes
+    in ascending int64 key order, the K live keys first and then the
+    sentinel (key int64 max, part and slot -1).  A fixed length keeps the
+    search's shapes as long as the planes' capacity.  ``pending`` holds the
+    (ids, parts, slots) the host index gained since, in the order of the
+    merges that inserted them; the next kernel GET brings them in."""
+
+    lo: jax.Array
+    hi: jax.Array
+    part: jax.Array
+    slot: jax.Array
+    pending: list = dataclasses.field(default_factory=list)
+
+    def planes(self) -> tuple[jax.Array, ...]:
+        return self.lo, self.hi, self.part, self.slot
+
+
+@dataclasses.dataclass
 class DeviceTableState:
     """Device-resident truth for one table: the exact plane layout both
-    Pallas kernels scan.  int64 keys/timestamps live as (lo, hi) int32
-    planes (TPU vector compare is 32-bit native)."""
+    Pallas kernels scan, and the sorted key index GETs search.  int64
+    keys/timestamps live as (lo, hi) int32 planes (TPU vector compare is
+    32-bit native)."""
 
     keys_lo: jax.Array  # (P, C) int32, -1 = empty
     keys_hi: jax.Array  # (P, C) int32
@@ -230,6 +258,7 @@ class DeviceTableState:
     cr_lo: jax.Array  # (P, C) int32 creation_ts planes
     cr_hi: jax.Array
     values: jax.Array  # (P, C, device_width(D)) float32, zero past D
+    index: DeviceKeyIndex
 
     def planes(self) -> tuple[jax.Array, ...]:
         return (
@@ -238,7 +267,10 @@ class DeviceTableState:
         )
 
     def nbytes(self) -> int:
-        return sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in self.planes())
+        return sum(
+            int(np.prod(p.shape)) * p.dtype.itemsize
+            for p in self.planes() + self.index.planes()
+        )
 
 
 @dataclasses.dataclass
@@ -295,6 +327,8 @@ class OnlineStore:
             "d2h_bytes": 0,
             "device_uploads": 0,
             "host_syncs": 0,
+            # incremental merges of inserted keys into the device key index
+            "index_updates": 0,
         }
 
     # -- lifecycle ----------------------------------------------------------
@@ -358,12 +392,50 @@ class OnlineStore:
                 cr_lo=jnp.asarray(clo),
                 cr_hi=jnp.asarray(chi),
                 values=values,
+                index=self._upload_index(t),
             )
             self.transfers["h2d_bytes"] += _nbytes(
                 t.keys_lo, t.keys_hi, elo, ehi, clo, chi, t.values
             )
             self.transfers["device_uploads"] += 1
         return t.device
+
+    def _upload_index(self, t: _PartitionedTable) -> DeviceKeyIndex:
+        """The whole host index, padded to P·C entries with the sentinel."""
+        n, k = t.keys_lo.size, len(t.idx_keys)
+        planes = np.empty((4, n), np.int32)
+        planes[0, :k], planes[1, :k] = lookup_ops.split_i64(t.idx_keys)
+        planes[2, :k] = t.idx_part
+        planes[3, :k] = t.idx_slot
+        planes[:, k:] = _INDEX_PAD[:, None]
+        self.transfers["h2d_bytes"] += planes.nbytes
+        return DeviceKeyIndex(*(jnp.asarray(p) for p in planes))
+
+    def _sync_index(self, t: _PartitionedTable) -> int:
+        """Bring the device key index level with the host's before a GET:
+        merge in the keys inserted since (their bytes alone go up), or
+        upload it whole where they outnumber the keys it holds.  Returns
+        the number of keys brought in."""
+        ix = t.device.index
+        if not ix.pending:
+            return 0
+        ids, parts, slots = (np.concatenate(c) for c in zip(*ix.pending))
+        n = len(ids)
+        if n > len(t.idx_keys) - n:
+            t.device.index = self._upload_index(t)
+            return n
+        order = np.argsort(ids)  # unique keys: stability irrelevant
+        new = np.empty((4, _bucket(n)), np.int32)
+        new[0, :n], new[1, :n] = lookup_ops.split_i64(ids[order])
+        new[2, :n] = parts[order]
+        new[3, :n] = slots[order]
+        new[:, n:] = _INDEX_PAD[:, None]
+        t.device.index = DeviceKeyIndex(
+            *lookup_ops.merge_index(*ix.planes(), jnp.asarray(new))
+        )
+        self.transfers["h2d_bytes"] += new.nbytes
+        self.transfers["index_updates"] += 1
+        return n
 
     def _sync_host(self, t: _PartitionedTable) -> None:
         """Refresh the host ev/cr/values mirrors from device truth (lazy:
@@ -438,12 +510,15 @@ class OnlineStore:
         parts: np.ndarray,
         slots: np.ndarray,
     ) -> None:
-        """Bulk-insert (already absent) ids, keeping the index sorted."""
+        """Bulk-insert (already absent) ids, keeping the index sorted; a
+        resident device index queues them for the next kernel GET."""
         order = np.argsort(new_ids)  # unique keys: stability irrelevant
         t.idx_keys, t.idx_part, t.idx_slot = merge_sorted(
             [t.idx_keys, t.idx_part, t.idx_slot],
             [new_ids[order], parts[order], slots[order]],
         )
+        if t.device is not None:
+            t.device.index.pending.append((new_ids, parts, slots))
 
     # -- slot assignment (shared by all engines) ----------------------------
     def _assign_slots(self, key: tuple[str, int], parts_o: np.ndarray) -> np.ndarray:
@@ -725,7 +800,7 @@ class OnlineStore:
                 jnp.asarray(welo), jnp.asarray(wehi),
                 jnp.asarray(cr_planes), jnp.asarray(wf),
             )
-            t.device = DeviceTableState(*out)
+            t.device = DeviceTableState(*out, index=dev.index)
             t.host_stale = True
             self.transfers["h2d_bytes"] += _nbytes(
                 p32, s32, klo, khi, isnew, welo, wehi, cr_planes, wf
@@ -868,10 +943,11 @@ class OnlineStore:
         """Batched GET.  Returns (values (B, D) float32, found (B,) bool).
         TTL-expired records count as not found.
 
-        ``use_kernel=True`` serves entirely from device truth (resident key
-        scan + on-device row gather, O(batch) traffic); ``use_kernel=False``
-        serves from the host mirror, syncing it first if a kernel merge left
-        it stale — both paths return byte-identical answers."""
+        ``use_kernel=True`` serves entirely from device truth (search of the
+        resident key index + on-device row gather, O(batch) traffic);
+        ``use_kernel=False`` serves from the host mirror, syncing it first if
+        a kernel merge left it stale — both paths return byte-identical
+        answers."""
         return self.lookup_encoded(
             name, version, encode_keys(id_columns), now=now, use_kernel=use_kernel
         )[:2]
@@ -912,35 +988,31 @@ class OnlineStore:
                 dev = self._ensure_device(t)
                 if sp:
                     sp.set(pad_cols=_pad_cols(spec))
-                q_lo, q_hi, part, pos = lookup_ops.route_queries(
-                    self.num_partitions, ids
-                )
-                with SPANS.span("fs.store.lookup.scan"):
-                    slots = np.asarray(
-                        lookup_ops.lookup(
-                            dev.keys_lo, dev.keys_hi,
-                            jnp.asarray(q_lo), jnp.asarray(q_hi),
-                        )
+                with SPANS.span("fs.store.lookup.scan") as scan:
+                    # pads search key 0; their answers are cut off below
+                    q = np.zeros((2, _bucket(b)), np.int32)
+                    q[0, :b], q[1, :b] = lookup_ops.split_i64(ids)
+                    merged = self._sync_index(t)
+                    # misses come back at (0, 0), clamped on the device;
+                    # masked below
+                    part, slot, found = lookup_ops.lookup(
+                        *dev.index.planes(), jnp.asarray(q)
                     )
-                self.transfers["h2d_bytes"] += _nbytes(q_lo, q_hi)
-                self.transfers["d2h_bytes"] += _nbytes(slots)
-                got = slots[part, pos]
-                found = got >= 0
-                bb = _bucket(b)
-                p32 = np.zeros(bb, np.int32)
-                s32 = np.zeros(bb, np.int32)
-                p32[:b] = part
-                s32[:b] = np.maximum(got, 0)  # clamp misses; masked below
+                    if scan:
+                        scan.set(index_merged=merged)
                 with SPANS.span("fs.store.lookup.gather"):
-                    vals_d, crlo_d, crhi_d = lookup_ops.gather_rows(
-                        dev.values, dev.cr_lo, dev.cr_hi,
-                        jnp.asarray(p32), jnp.asarray(s32),
+                    rows = lookup_ops.gather_rows(
+                        dev.values, dev.cr_lo, dev.cr_hi, part, slot
                     )
-                    vals = np.array(np.asarray(vals_d)[:b, :d])
-                    cr_lo = np.asarray(crlo_d)[:b]
-                    cr_hi = np.asarray(crhi_d)[:b]
-                self.transfers["h2d_bytes"] += 2 * bb * 4
-                self.transfers["d2h_bytes"] += bb * (dev.values.shape[-1] * 4 + 8)
+                    # every copy starts before the one wait
+                    vals, cr_lo, cr_hi, found = jax.device_get((*rows, found))
+                # copies: callers own (and may write) what a GET returns
+                vals, found = np.array(vals[:b, :d]), np.array(found[:b])
+                cr_lo, cr_hi = cr_lo[:b], cr_hi[:b]
+                self.transfers["h2d_bytes"] += q.nbytes
+                self.transfers["d2h_bytes"] += q.shape[1] * (
+                    dev.values.shape[-1] * 4 + 8 + 1
+                )
                 vals[~found] = 0.0
                 cr = lookup_ops.combine_i64(cr_lo, cr_hi)
                 if now is not None and ttl is not None:

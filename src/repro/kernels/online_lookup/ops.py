@@ -1,22 +1,26 @@
-"""jit'd wrapper + host routing for the online-lookup kernel.
+"""jit'd wrappers + host routing for the online store's GET path.
 
 The online store (core/online_store.py) keeps its device mirror in the
-partitioned layout this kernel expects.  This module provides:
+partitioned (P, C) slot layout, plus a device copy of its sorted key
+index.  This module provides:
 
   * ``split_i64`` / ``partition_of`` — the shared hashing/key-splitting
-    helpers (numpy, host-side) so the store and the kernel agree bit-for-bit.
-  * ``lookup`` — the jit'd kernel wrapper over pre-routed (P, Q) queries.
-    Passing device-RESIDENT key planes (jax arrays) makes this transfer-free
-    on the table side: only the routed queries go up and the (P, Q) slot
-    indices come back — O(batch), never O(P·C).
+    helpers (numpy, host-side) so the store and the kernels agree
+    bit-for-bit.
+  * ``lookup`` — the GET's slot resolution: a fixed-step binary search of
+    the device-resident sorted key index for a flat (B,) query batch,
+    returning device (part, slot, found).  Its work is O(B · log(P·C)),
+    never O(P·C).
+  * ``merge_index`` — brings keys inserted on the host into the device
+    index in one O(P·C) program, with O(inserted) bytes uploaded.
   * ``gather_rows`` — the resident GET's second half: fetch feature rows and
     creation_ts planes at resolved (part, slot) coords on device, so a
     lookup returns (B, D) + (B,) arrays without the host ever holding the
     value planes.
-  * ``route_and_lookup`` — host-side convenience: route a flat id batch to
-    partitions, pad, run the kernel, gather values, un-permute.  Used by the
-    host-mirror path and tests; the store's kernel GET composes the resident
-    pieces instead.
+  * ``scan_slots`` — the Pallas scan of every slot of pre-routed (P, Q)
+    queries; ``route_and_lookup`` — host-side convenience that routes a flat
+    id batch to partitions, scans, gathers values and un-permutes.  The
+    store's GET no longer uses them.
 """
 
 from __future__ import annotations
@@ -35,10 +39,12 @@ __all__ = [
     "partition_of",
     "gather_rows",
     "lookup",
+    "merge_index",
     "pow2_bucket",
     "route_and_lookup",
     "route_flat",
     "route_queries",
+    "scan_slots",
 ]
 
 _LANE = 128
@@ -119,7 +125,7 @@ def route_flat(
 
 
 @functools.partial(jax.jit, static_argnames=("slot_block",))
-def lookup(
+def scan_slots(
     keys_lo: jnp.ndarray,
     keys_hi: jnp.ndarray,
     q_lo: jnp.ndarray,
@@ -183,6 +189,85 @@ def route_queries(
     return q_lo, q_hi, part, pos
 
 
+_SIGN = np.int32(np.iinfo(np.int32).min)
+
+
+def _below(a_lo, a_hi, b_lo, b_hi):
+    """(lo, hi) int32 pairs compared in int64 order: hi signed, lo unsigned."""
+    return (a_hi < b_hi) | ((a_hi == b_hi) & ((a_lo ^ _SIGN) < (b_lo ^ _SIGN)))
+
+
+def _count_below(keys_lo, keys_hi, q_lo, q_hi):
+    """Keys of the sorted (N,) planes below each query: a branchless binary
+    search of ``N.bit_length()`` steps, each one gather per plane of the
+    queries' current probes.  The step count is static, so every batch of a
+    bucket runs one compiled program, and nothing (B, N)-sized is built."""
+    n = keys_lo.shape[0]
+    pos = jnp.zeros(q_lo.shape, jnp.int32)
+    for s in reversed(range(n.bit_length())):
+        cand = pos + (1 << s)
+        at = jnp.minimum(cand, n) - 1
+        take = (cand <= n) & _below(keys_lo[at], keys_hi[at], q_lo, q_hi)
+        pos = jnp.where(take, cand, pos)
+    return pos
+
+
+@jax.jit
+def lookup(
+    idx_lo: jnp.ndarray,
+    idx_hi: jnp.ndarray,
+    idx_part: jnp.ndarray,
+    idx_slot: jnp.ndarray,
+    queries: jnp.ndarray,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Resolve keys against the device's sorted key index.
+
+    The index is four (N,) int32 planes in ascending int64 key order, its
+    unused tail marked by ``idx_part`` -1; ``queries`` is (2, B) int32, the
+    keys' lo and hi words.  Returns (part, slot, found), each (B,): the
+    key's coordinates where found and (0, 0) elsewhere, so they feed
+    ``gather_rows`` directly and the caller masks misses after."""
+    q_lo, q_hi = queries[0], queries[1]
+    at = jnp.minimum(_count_below(idx_lo, idx_hi, q_lo, q_hi), idx_lo.shape[0] - 1)
+    part = idx_part[at]
+    found = (idx_lo[at] == q_lo) & (idx_hi[at] == q_hi) & (part >= 0)
+    return jnp.where(found, part, 0), jnp.where(found, idx_slot[at], 0), found
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
+def merge_index(
+    idx_lo: jnp.ndarray,
+    idx_hi: jnp.ndarray,
+    idx_part: jnp.ndarray,
+    idx_slot: jnp.ndarray,
+    new: jnp.ndarray,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Place new entries into the donated sorted key index.
+
+    ``new`` is (4, M) int32: lo, hi, part, slot of keys the index does not
+    hold, ascending, then padding with part -1.  Each new key lands at its
+    rank plus the old keys below it; each old entry moves up by the new
+    keys below it, a prefix count.  The live keys of both fit in N, since
+    each holds a slot, and the old tail fills what is left."""
+    n, m = idx_lo.shape[0], new.shape[1]
+    live = new[2] >= 0
+    rank = _count_below(idx_lo, idx_hi, new[0], new[1])
+    new_at = jnp.where(live, jnp.arange(m, dtype=jnp.int32) + rank, n)
+    shift = jnp.cumsum(
+        jnp.zeros(n, jnp.int32).at[rank].add(live.astype(jnp.int32), mode="drop")
+    )
+    old_at = jnp.arange(n, dtype=jnp.int32) + shift
+
+    def place(old, fresh):
+        out = jnp.zeros(n, jnp.int32).at[old_at].set(old, mode="drop")
+        return out.at[new_at].set(fresh, mode="drop")
+
+    return tuple(
+        place(old, new[i])
+        for i, old in enumerate((idx_lo, idx_hi, idx_part, idx_slot))
+    )
+
+
 @jax.jit
 def gather_rows(
     values: jnp.ndarray,
@@ -216,7 +301,7 @@ def route_and_lookup(
     q_lo, q_hi, part, slot_in_part = route_queries(num_p, ids)
 
     slots = np.asarray(
-        lookup(
+        scan_slots(
             jnp.asarray(keys_lo),
             jnp.asarray(keys_hi),
             jnp.asarray(q_lo),

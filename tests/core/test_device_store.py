@@ -349,3 +349,101 @@ def test_padded_value_plane_matches_vector_engine(tmp_path, n_feats):
         k.lookup_encoded("fs", 1, ids[:5])
     (lookup,) = SPANS.spans("fs.store.lookup")
     assert lookup.attrs["pad_cols"] == width - n_feats
+
+
+# -- the device key index the kernel GET searches -----------------------------
+
+
+def test_device_index_exact_through_merges_sweep_and_grow():
+    """Kernel merges that insert, override and no-op, a sweep and a capacity
+    doubling, each followed by kernel GETs: the device index follows the
+    host's, so kernel and host-mirror GETs agree byte for byte, TTL
+    included, after every step."""
+    spec = make_spec(ttl=500, n_feats=3)
+    store = OnlineStore(num_partitions=4, initial_capacity=64, merge_engine="kernel")
+    rng = np.random.default_rng(11)
+    probes = np.arange(-5, 400, dtype=np.int64)
+
+    def frame(ids, ts):
+        return Table({
+            "entity_id": np.asarray(ids, np.int64),
+            "ts": np.full(len(ids), ts, np.int64),
+            **{f"f{i}": rng.random(len(ids)).astype(np.float32) for i in range(3)},
+        })
+
+    def same_gets(now, label):
+        got_k = store.lookup_encoded("fs", 1, probes, now=now)
+        got_h = store.lookup_encoded("fs", 1, probes, now=now, use_kernel=False)
+        for a, b in zip(got_k, got_h):
+            assert a.dtype == b.dtype and a.shape == b.shape, label
+            assert a.tobytes() == b.tobytes(), label
+
+    steps = [
+        ("load", lambda: store.merge(spec, frame(np.arange(0, 150), 10), 1_000)),
+        ("insert", lambda: store.merge(spec, frame(np.arange(150, 170), 10), 1_100)),
+        ("override", lambda: store.merge(spec, frame(np.arange(0, 40), 20), 1_200)),
+        ("no-op", lambda: store.merge(spec, frame(np.arange(40, 60), 5), 1_300)),
+        ("mixed", lambda: store.merge(
+            spec, frame(np.r_[170:180, 60:70, 300:305], 15), 1_400)),
+        ("sweep", lambda: store.sweep("fs", 1, now=1_550)),
+        ("reinsert", lambda: store.merge(spec, frame(np.arange(100, 130), 30), 1_600)),
+        ("grow", lambda: store.merge(spec, frame(np.arange(180, 400), 30), 1_700)),
+        ("insert after grow", lambda: store.merge(spec, frame([-3, 399, 2], 40), 1_800)),
+    ]
+    updates = []
+    for label, step in steps:
+        step()
+        for now in (None, 1_560, 2_050, 2_150):
+            same_gets(now, f"{label} @ {now}")
+        updates.append(store.transfers["index_updates"])
+    assert store.num_records("fs", 1) > 0 and store.overrides and store.noops
+    assert store._tables[spec.key].keys_lo.shape[1] > 64
+    # the inserts after the load were merged into the device index in place
+    assert updates[1] == 1 and updates[-1] > updates[1]
+
+
+def test_index_updates_move_o_inserted_bytes():
+    """A kernel merge that inserts does no device work on the index; the
+    next kernel GET uploads O(inserted) index bytes in one index update,
+    and GETs with nothing pending upload only their queries."""
+    spec = make_spec(n_feats=4)
+    store = OnlineStore(num_partitions=8, initial_capacity=8192, merge_engine="kernel")
+    rng = np.random.default_rng(12)
+    store.merge(spec, make_frame(rng, 6_000, 6_000, 100, n_feats=4), 1_000)
+    batch = 512
+    ids = rng.integers(0, 6_000, batch).astype(np.int64)
+    store.lookup_encoded("fs", 1, ids)  # brings the load's keys in, warms
+    index = store.device_state("fs", 1).index
+    assert not index.pending
+    index_bytes = 16 * 8 * 8192
+
+    store.reset_transfer_stats()
+    for _ in range(10):
+        store.lookup_encoded("fs", 1, ids)
+    tx = store.transfer_stats()
+    assert tx["index_updates"] == 0 and tx["device_uploads"] == 0
+    record_bytes = 8 * 4 + 4 * 4
+    assert (tx["h2d_bytes"] + tx["d2h_bytes"]) / 10 <= o_batch_byte_budget(
+        batch, record_bytes
+    )
+    assert tx["h2d_bytes"] == 10 * 2 * 4 * batch  # the queries alone
+
+    n = 37
+    new_ids = np.arange(10_000, 10_000 + n, dtype=np.int64)
+    store.merge(spec, Table({
+        "entity_id": new_ids,
+        "ts": np.full(n, 5, np.int64),
+        **{f"f{i}": np.full(n, i, np.float32) for i in range(4)},
+    }), 2_000)
+    assert store.device_state("fs", 1).index is index  # untouched by the merge
+    assert [len(p[0]) for p in index.pending] == [n]
+    store.reset_transfer_stats()
+    vals, found, _ = store.lookup_encoded("fs", 1, new_ids)
+    tx = store.transfer_stats()
+    assert tx["index_updates"] == 1 and tx["device_uploads"] == 0
+    assert tx["h2d_bytes"] == 2 * 4 * 128 + 16 * 128  # queries + n entries' bucket
+    assert tx["h2d_bytes"] < index_bytes / 100
+    assert found.all() and (vals == np.arange(4, dtype=np.float32)).all()
+    store.reset_transfer_stats()
+    store.lookup_encoded("fs", 1, new_ids)
+    assert store.transfer_stats()["index_updates"] == 0

@@ -19,6 +19,7 @@ from repro.core.featurestore import FeatureStore
 from repro.core.monitoring import SPANS, HealthMonitor, SpanRecorder
 from repro.core.online_store import OnlineStore
 from repro.core.serving import ServingConfig, ServingFront
+from repro.core.table import Table
 from repro.data.sources import SyntheticEventSource
 from tests.core.test_serving import make_frame, make_spec
 
@@ -189,6 +190,24 @@ def test_host_engine_lookup_has_no_kernel_children(tmp_path):
     assert lookup.attrs == {"keys": 5, "rows": 5}
     assert not SPANS.spans("fs.store.lookup.scan")
     assert not SPANS.spans("fs.store.lookup.gather")
+
+
+def test_kernel_lookup_scan_records_index_merged(tmp_path):
+    """``fs.store.lookup.scan`` says how many inserted keys the GET first
+    merged into the device key index: the inserts of the merge before it,
+    then none."""
+    store, spec, rng = _kernel_store()
+    store.lookup_encoded("fs", 1, np.arange(4, dtype=np.int64))
+    frame = make_frame(rng, 10, 10, 50)
+    cols = {c: frame[c] for c in ("ts", "f0", "f1")}
+    cols["entity_id"] = np.arange(100, 110, dtype=np.int64)
+    store.merge(spec, Table(cols), 2_000)
+    with profiled(tmp_path):
+        for _ in range(2):
+            store.lookup_encoded("fs", 1, np.arange(95, 105, dtype=np.int64))
+    assert [s.attrs for s in SPANS.spans("fs.store.lookup.scan")] == [
+        {"index_merged": 10}, {"index_merged": 0},
+    ]
 
 
 def test_kernel_merge_on_stale_mirror_records_resolve(tmp_path):

@@ -1,4 +1,4 @@
-"""online_lookup kernel vs oracle: routing, padding, sentinel handling."""
+"""online_lookup's Pallas slot scan vs oracle: routing, padding, sentinel handling."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -6,9 +6,9 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.online_lookup.ops import (
-    lookup,
     partition_of,
     route_and_lookup,
+    scan_slots,
     split_i64,
 )
 from repro.kernels.online_lookup.ref import lookup_ref
@@ -75,7 +75,7 @@ def test_lookup_vs_ref(num_p, cap, q):
             else:
                 q_lo[p, i] = rng.integers(0, 2**31 - 1)
                 q_hi[p, i] = 101  # plane-2 value no live key uses
-    got = lookup(
+    got = scan_slots(
         jnp.asarray(keys_lo), jnp.asarray(keys_hi), jnp.asarray(q_lo), jnp.asarray(q_hi)
     )
     want = lookup_ref(
@@ -92,7 +92,7 @@ def test_lookup_slot_block_sweep(slot_block):
     keys_hi = np.zeros((num_p, cap), np.int32)
     q_lo = keys_lo[:, :q].copy()
     q_hi = np.zeros((num_p, q), np.int32)
-    got = lookup(
+    got = scan_slots(
         jnp.asarray(keys_lo), jnp.asarray(keys_hi),
         jnp.asarray(q_lo), jnp.asarray(q_hi), slot_block=slot_block,
     )

@@ -11,7 +11,8 @@ partitions x 65,536 slots, 8 features), 2^21-row merge batches, a
 benchmark's table (16 x 2^18 slots) over the feature widths the store's
 ``device_width`` rule gives, and must carry no plane-sized temporary: at
 a width the compiler stores feature-major, every call would first
-relayout the whole plane.
+relayout the whole plane.  The GET's search of the key index compiles at
+that table too, and must do O(B log N) work, not O(N).
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported, so every xdist worker collects the same tests and only
@@ -28,7 +29,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.online_store import device_width
 from repro.kernels.online_lookup.kernel import lookup_kernel_call
-from repro.kernels.online_lookup.ops import gather_rows
+from repro.kernels.online_lookup.ops import gather_rows, lookup, merge_index
 from repro.kernels.online_merge.kernel import merge_kernel_call
 from repro.kernels.online_merge.ops import merge_at_slots
 from repro.kernels.pit_join.kernel import pit_search_kernel_call
@@ -210,3 +211,45 @@ def test_value_plane_programs_copy_free(one_chip, program, features):
     _check(
         lowered.compile(), kernel=False, plane_bytes=PARTS * BENCH_SLOTS * width * 4
     )
+
+
+@pytest.mark.parametrize("batch", [128, 256, SERVE_BATCH])
+def test_index_search_compiles_without_table_work(one_chip, batch):
+    """The GET's key search over the benchmark's index (16 x 2^18 entries)
+    and the gather it feeds.  The search does O(B log N) operations, at
+    most 64 a query and step, and holds no temporary: one that compared
+    every entry (``searchsorted(method="compare_all")``, the slot scan)
+    does B x N, and any pass over the index at least N, more than the
+    bound at the small batches.  The TPU cost model charges each gather its
+    whole operand as bytes accessed, so bytes cannot tell these apart."""
+    n = PARTS * BENCH_SLOTS
+    index = _spec(one_chip, (n,))
+    compiled = lookup.lower(
+        index, index, index, index, _spec(one_chip, (2, batch))
+    ).compile()
+    _check(compiled, kernel=False)
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["flops"] < 64 * batch * n.bit_length(), (cost["flops"], n)
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+    values = _spec(one_chip, (PARTS, BENCH_SLOTS, device_width(250)), jnp.float32)
+    plane = _spec(one_chip, (PARTS, BENCH_SLOTS))
+    coords = _spec(one_chip, (batch,))
+    _check(
+        gather_rows.lower(values, plane, plane, coords, coords).compile(),
+        kernel=False, plane_bytes=PARTS * BENCH_SLOTS * device_width(250) * 4,
+    )
+
+
+def test_index_merge_compiles_in_place(one_chip):
+    """Merging a bucket of inserted keys into the benchmark's index reuses
+    the donated planes: no second index is allocated beside them."""
+    n = PARTS * BENCH_SLOTS
+    index = _spec(one_chip, (n,))
+    compiled = merge_index.lower(
+        index, index, index, index, _spec(one_chip, (4, 128))
+    ).compile()
+    _check(compiled, kernel=False)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 4 * n * 4
+    assert mem.temp_size_in_bytes < n * 4, mem.temp_size_in_bytes
