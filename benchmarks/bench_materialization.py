@@ -175,75 +175,6 @@ class _SeedStores:
                     self.values[pp, slot] = feats[i]
 
 
-class _Pr1KernelStore(OnlineStore):
-    """Faithful replica of PR 1's kernel engine, pinned here so the
-    device-resident trajectory baseline can't drift: identical host planning
-    (plan + sorted-index slot resolution), but every merge streams the FULL
-    table through the Pallas scan kernel with a host round-trip — re-upload
-    all (P, C) planes, pull them all back — instead of the resident
-    donated-buffer scatter.  Measured in the same run as the real engines so
-    the speedup ratio is machine-condition-independent."""
-
-    def _merge_vector(
-        self, key, ids, event_ts, frame, fnames, creation_ts, *, use_kernel=True
-    ):
-        from repro.core.merge_engine import INT64_MIN, plan_online_batch
-        from repro.kernels.online_lookup import ops as lookup_ops
-        from repro.kernels.online_merge import ops as merge_ops
-
-        t = self._tables[key]
-        t.slot_cache = None
-
-        def resolve(uids):
-            part_e, slot_e, found = self._index_find(t, uids)
-            resolve.parts, resolve.slots = part_e, slot_e
-            return t.event_ts[part_e, slot_e], t.creation_ts[part_e, slot_e], found
-
-        plan = plan_online_batch(ids, event_ts, creation_ts, resolve)
-        part_e, slot_e = resolve.parts, resolve.slots
-        found = ~plan.is_new
-        wfeats = np.stack(
-            [np.asarray(frame[n], np.float32)[plan.winner_row] for n in fnames],
-            axis=1,
-        )
-        self.inserts += plan.inserts
-        self.overrides += plan.overrides
-        self.noops += plan.noops
-        new = plan.is_new
-        if new.any():
-            ins_ids = plan.uids[new]
-            arrival = np.argsort(plan.first_row[new], kind="stable")
-            ins_ids_o = ins_ids[arrival]
-            parts_o = lookup_ops.partition_of(ins_ids_o, self.num_partitions)
-            counts = np.bincount(parts_o, minlength=self.num_partitions)
-            while (t.fill + counts).max() > t.keys_lo.shape[1]:
-                self._grow(key)
-            po = np.argsort(parts_o, kind="stable")
-            parts_sorted = parts_o[po]
-            rank = np.arange(len(po)) - np.searchsorted(parts_sorted, parts_sorted)
-            slots_o = np.empty(len(po), np.int64)
-            slots_o[po] = t.fill[parts_sorted] + rank
-            t.fill += counts
-            lo, hi = lookup_ops.split_i64(ins_ids_o)
-            t.keys_lo[parts_o, slots_o] = lo
-            t.keys_hi[parts_o, slots_o] = hi
-            t.keys_full[parts_o, slots_o] = ins_ids_o
-            self._index_insert(t, ins_ids_o, parts_o, slots_o)
-            t.event_ts[parts_o, slots_o] = INT64_MIN
-            t.creation_ts[parts_o, slots_o] = INT64_MIN
-        t.event_ts, t.creation_ts, t.values = merge_ops.route_and_merge(
-            t.keys_lo, t.keys_hi, t.event_ts, t.creation_ts, t.values,
-            plan.uids, plan.winner_ev, wfeats,
-            creation_ts,
-        )
-        return {
-            "engine": "kernel_pr1", "inserts": plan.inserts,
-            "overrides": plan.overrides, "noops": plan.noops,
-            "touched_parts": np.empty(0, np.int64),
-            "touched_slots": np.empty(0, np.int64),
-        }
-
-
 def bench_merge_engines(
     window_rows: int = 100_000, batches: int = 1, trials: int = 5
 ) -> dict:
@@ -281,17 +212,11 @@ def bench_merge_engines(
     out["seed"] = _drive(
         lambda: _SeedStores(spec), lambda st, f, cr: st.merge(f, cr)
     )
-    for engine, make_online in (
-        ("loop", OnlineStore),
-        ("vector", OnlineStore),
-        ("kernel", OnlineStore),
-        ("kernel_pr1", _Pr1KernelStore),
-    ):
-        store_engine = "kernel" if engine == "kernel_pr1" else engine
+    for engine in ("loop", "vector", "kernel"):
         out[engine] = _drive(
             lambda: (
-                OfflineStore(num_shards=4, merge_engine=store_engine),
-                make_online(merge_engine=store_engine),
+                OfflineStore(num_shards=4, merge_engine=engine),
+                OnlineStore(merge_engine=engine),
             ),
             lambda st, f, cr: (st[0].merge(spec, f, cr), st[1].merge(spec, f, cr)),
         )
@@ -300,12 +225,6 @@ def bench_merge_engines(
     )
     out["speedup_vs_loop_x"] = round(
         out["vector"]["rows_per_s"] / max(out["loop"]["rows_per_s"], 1), 1
-    )
-    # device-resident trajectory (ISSUE 2 acceptance): PR 1's kernel path
-    # re-uploaded every (P, C) plane per merge and pulled them all back —
-    # the resident engine must beat that same-run replica by >= 3x
-    out["kernel"]["speedup_vs_pr1_kernel_x"] = round(
-        out["kernel"]["rows_per_s"] / max(out["kernel_pr1"]["rows_per_s"], 1), 1
     )
     return out
 

@@ -2,8 +2,8 @@
 
   * GET: batched lookups/s and per-request latency percentiles for BOTH
     serving paths — host mirror (numpy compare-match) and the device-resident
-    kernel path (Pallas scan over resident key planes + on-device row
-    gather), steady-state post-warmup
+    kernel path (search of the resident key index + on-device row gather),
+    steady-state post-warmup
   * MERGE (Algorithm 2): records/s merged into the online store, including
     the stale-update no-op path (idempotence under retries)
   * MERGE ENGINES: the per-row loop reference vs the vectorized engine vs

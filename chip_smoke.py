@@ -16,9 +16,9 @@ calls, and each phase is checked against a plain reference:
   rolling      the 6-hour source window through the DSL with the kernel and
                with ``backend="xla"``: sums and means must be allclose.
   serve        8 GETs of 4096 ids (about 10% unknown) through
-               ``fs.get_online_features`` — serving front and Pallas lookup
-               kernel — byte-identical to the twin's host GET and to the
-               store's own ``lookup_encoded(use_kernel=False)``.
+               ``fs.get_online_features`` — serving front, key-index search
+               and row gather — byte-identical to the twin's host GET and to
+               the store's own ``lookup_encoded(use_kernel=False)``.
   retrieve     ``fs.get_offline_features`` on a 65,536-row spine through the
                Pallas PIT kernel: the kernel's indices must equal
                ``pit_search_ref`` and the returned rows must be the rows at

@@ -3,8 +3,9 @@
     PYTHONPATH=src python examples/online_serving.py --requests 8 --new-tokens 16
 
 Each request names a session (entity id); the ONLINE store serves the
-session's latest materialized context through the Pallas lookup kernel, the
-model prefills it and decodes new tokens with a KV cache.  Thin veneer over
+session's latest materialized context through the device GET (a search of
+the resident key index and a row gather), the model prefills it and decodes
+new tokens with a KV cache.  Thin veneer over
 repro.launch.serve (the production driver), plus a skew check: the served
 online context must equal the offline store's latest record for the same id
 (the paper's central online/offline-consistency promise).

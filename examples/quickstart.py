@@ -10,7 +10,7 @@ Walks through every §2.1 capability on a synthetic transaction stream:
       the paper's customer-churn example: 30day_transactions_sum et al.)
   3.  scheduled incremental materialization (tick) + on-demand backfill
   4.  point-in-time-correct offline retrieval (a training frame)  [§4.4]
-  5.  low-latency online retrieval (the Pallas lookup kernel)     [§3.1.4]
+  5.  low-latency online retrieval (the device key-index GET)     [§3.1.4]
   6.  offline/online consistency check + Fig.5 record semantics   [§4.5]
   7.  feature->model lineage                                      [§4.6]
 """

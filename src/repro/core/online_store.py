@@ -16,15 +16,17 @@ Layout: the paper's storage-partitioning scheme applied to device memory —
 hash-partitioned (P, C) slot tables of key planes, timestamp planes and
 (P, C, D) feature values, plus a sorted key index that maps a key to its
 (part, slot); GETs search it (kernels/online_lookup), writes resolve
-against it on the host (kernels/online_merge).  On the device the value
-plane is (P, C, ``device_width(D)``), zero past column D, so that the
-gather and the merge need not relayout the whole plane on every call.
+against it on the host (kernels/online_merge).  The key planes live on
+the host only: the device holds the timestamp and value planes and its
+copy of the index.  On the device the value plane is
+(P, C, ``device_width(D)``), zero past column D, so that the gather and
+the merge need not relayout the whole plane on every call.
 
 Host-mirror / device-truth protocol
 -----------------------------------
 The ``kernel`` engine keeps the planes DEVICE-RESIDENT (``DeviceTableState``:
-int32 key/timestamp planes + f32 values as jax arrays) and device memory is
-the source of truth between kernel merges/lookups:
+int32 timestamp planes + f32 values as jax arrays, and the key index) and
+device memory is the source of truth between kernel merges/lookups:
 
   * a kernel MERGE plans the batch on host (sorted key index -> slots, exact
     Algorithm-2 tallies from the plan), then applies it with ONE donated
@@ -45,9 +47,10 @@ the source of truth between kernel merges/lookups:
   * host MUTATIONS (vector/loop merges, ``sweep``, ``_grow``) sync first and
     then DROP the device state, index included (host becomes sole truth
     again); the next kernel operation re-uploads lazily.  Slot assignment,
-    the sorted key index, ``keys_full``, and ``fill`` always live on host
-    (inserts resolve there), and inserted keys are scattered into the device
-    planes inside the same donated update.
+    the key planes, the sorted key index, and ``fill`` always live on host
+    (inserts resolve there); a kernel merge's inserted keys reach the device
+    only through its index, and the donated update writes their records
+    into the slots the host assigned.
 
 ``transfers`` tallies every host<->device byte the store moves, so tests and
 benchmarks can assert the steady-state cycle is O(batch).
@@ -246,13 +249,11 @@ class DeviceKeyIndex:
 
 @dataclasses.dataclass
 class DeviceTableState:
-    """Device-resident truth for one table: the exact plane layout both
-    Pallas kernels scan, and the sorted key index GETs search.  int64
-    keys/timestamps live as (lo, hi) int32 planes (TPU vector compare is
+    """Device-resident truth for one table: the five planes the merge
+    updates and the gathers read, and the sorted key index GETs search.
+    int64 timestamps live as (lo, hi) int32 planes (TPU vector compare is
     32-bit native)."""
 
-    keys_lo: jax.Array  # (P, C) int32, -1 = empty
-    keys_hi: jax.Array  # (P, C) int32
     ev_lo: jax.Array  # (P, C) int32 event_ts planes
     ev_hi: jax.Array
     cr_lo: jax.Array  # (P, C) int32 creation_ts planes
@@ -261,10 +262,7 @@ class DeviceTableState:
     index: DeviceKeyIndex
 
     def planes(self) -> tuple[jax.Array, ...]:
-        return (
-            self.keys_lo, self.keys_hi, self.ev_lo, self.ev_hi,
-            self.cr_lo, self.cr_hi, self.values,
-        )
+        return self.ev_lo, self.ev_hi, self.cr_lo, self.cr_hi, self.values
 
     def nbytes(self) -> int:
         return sum(
@@ -385,8 +383,6 @@ class OnlineStore:
                 values = _put_partition(values, np.int32(p), jnp.asarray(block))
                 values.block_until_ready()
             t.device = DeviceTableState(
-                keys_lo=jnp.asarray(t.keys_lo),
-                keys_hi=jnp.asarray(t.keys_hi),
                 ev_lo=jnp.asarray(elo),
                 ev_hi=jnp.asarray(ehi),
                 cr_lo=jnp.asarray(clo),
@@ -394,15 +390,13 @@ class OnlineStore:
                 values=values,
                 index=self._upload_index(t),
             )
-            self.transfers["h2d_bytes"] += _nbytes(
-                t.keys_lo, t.keys_hi, elo, ehi, clo, chi, t.values
-            )
+            self.transfers["h2d_bytes"] += _nbytes(elo, ehi, clo, chi, t.values)
             self.transfers["device_uploads"] += 1
         return t.device
 
     def _upload_index(self, t: _PartitionedTable) -> DeviceKeyIndex:
         """The whole host index, padded to P·C entries with the sentinel."""
-        n, k = t.keys_lo.size, len(t.idx_keys)
+        n, k = t.keys_full.size, len(t.idx_keys)
         planes = np.empty((4, n), np.int32)
         planes[0, :k], planes[1, :k] = lookup_ops.split_i64(t.idx_keys)
         planes[2, :k] = t.idx_part
@@ -439,8 +433,8 @@ class OnlineStore:
 
     def _sync_host(self, t: _PartitionedTable) -> None:
         """Refresh the host ev/cr/values mirrors from device truth (lazy:
-        no-op unless a kernel merge advanced the device planes).  Key planes
-        never need a pull — inserts keep them current on host."""
+        no-op unless a kernel merge advanced the device planes).  The key
+        planes live on the host alone, so they never need a pull."""
         if not t.host_stale:
             return
         d = t.device
@@ -529,7 +523,7 @@ class OnlineStore:
         t = self._tables[key]
         counts = np.bincount(parts_o, minlength=self.num_partitions)
         nfree = np.array([len(f) for f in t.free], np.int64)
-        while (t.fill + np.maximum(counts - nfree, 0)).max() > t.keys_lo.shape[1]:
+        while (t.fill + np.maximum(counts - nfree, 0)).max() > t.keys_full.shape[1]:
             self._grow(key)
         po = np.argsort(parts_o, kind="stable")
         parts_sorted = parts_o[po]
@@ -767,7 +761,7 @@ class OnlineStore:
 
         if use_kernel:
             # a grow inside _assign_slots dropped the device state; re-ensure
-            # (fresh upload already carries the just-inserted keys)
+            # (a fresh upload's index already holds the just-inserted keys)
             dev = self._ensure_device(t)
             gb = _bucket(g)
             p32 = np.zeros(gb, np.int32)
@@ -776,9 +770,6 @@ class OnlineStore:
             s32 = np.full(gb, _I32_MAX, np.int32)
             p32[:g] = gpart
             s32[:g] = gslot
-            klo = np.zeros(gb, np.int32)
-            khi = np.zeros(gb, np.int32)
-            klo[:g], khi[:g] = lookup_ops.split_i64(plan.uids)
             isnew = np.zeros(gb, bool)
             isnew[:g] = new
             welo = np.zeros(gb, np.int32)
@@ -795,15 +786,14 @@ class OnlineStore:
             )
             out = merge_ops.merge_at_slots(
                 *dev.planes(),
-                jnp.asarray(p32), jnp.asarray(s32),
-                jnp.asarray(klo), jnp.asarray(khi), jnp.asarray(isnew),
+                jnp.asarray(p32), jnp.asarray(s32), jnp.asarray(isnew),
                 jnp.asarray(welo), jnp.asarray(wehi),
                 jnp.asarray(cr_planes), jnp.asarray(wf),
             )
             t.device = DeviceTableState(*out, index=dev.index)
             t.host_stale = True
             self.transfers["h2d_bytes"] += _nbytes(
-                p32, s32, klo, khi, isnew, welo, wehi, cr_planes, wf
+                p32, s32, isnew, welo, wehi, cr_planes, wf
             )
         else:
             upd = plan.beat
@@ -876,7 +866,7 @@ class OnlineStore:
                 if t.free[p]:
                     slot = int(t.free[p].popleft())
                 else:
-                    if t.fill[p] >= t.keys_lo.shape[1]:
+                    if t.fill[p] >= t.keys_full.shape[1]:
                         self._grow(key)
                     slot = int(t.fill[p])
                     t.fill[p] += 1

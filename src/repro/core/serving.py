@@ -4,7 +4,7 @@ front of ``OnlineStore``.
 The paper's online store exists for one reason — low-latency point lookups
 at inference time — but a store reference alone is not a serving tier: at
 "millions of users" every caller holding the store would pay a full kernel
-dispatch per point GET (the ~ms Pallas dispatch dominates the lookup at
+dispatch per point GET (the ~ms device dispatch dominates the lookup at
 request-sized batches).  This module is the §2.1/§3.1.4 serving tier built
 from three mechanisms, each mapped to its paper motivation:
 

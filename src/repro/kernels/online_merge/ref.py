@@ -1,43 +1,39 @@
-"""Pure-numpy oracle for the partitioned latest-wins merge.
+"""Pure-numpy oracle for ``ops.merge_at_slots``.
 
-Operates on the un-split int64 view of the table (event/creation ts as
-int64, keys as int64), so the kernel's two-plane arithmetic is checked
-against ordinary integer comparisons.  Queries arrive routed: ids (P, Q)
-int64 with -2 padding (matches nothing), event_ts (P, Q), values (P, Q, D),
-one scalar creation_ts per batch.
+Works on the un-split int64 view of the table (event/creation ts as int64),
+so the device program's two-plane arithmetic is checked against ordinary
+integer comparisons.  The batch arrives at resolved (part, slot)
+coordinates, one record per coordinate, with one creation_ts per batch.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-__all__ = ["merge_ref"]
+__all__ = ["merge_at_slots_ref"]
 
 
-def merge_ref(
-    keys: np.ndarray,       # (P, C) int64, -1 empty
-    event_ts: np.ndarray,   # (P, C) int64
+def merge_at_slots_ref(
+    event_ts: np.ndarray,     # (P, C) int64
     creation_ts: np.ndarray,  # (P, C) int64
-    values: np.ndarray,     # (P, C, D) f32
-    q_ids: np.ndarray,      # (P, Q) int64, -2 padding
-    q_ev: np.ndarray,       # (P, Q) int64
-    q_values: np.ndarray,   # (P, Q, D) f32
+    values: np.ndarray,       # (P, C, D) f32
+    part: np.ndarray,         # (G,) int
+    slot: np.ndarray,         # (G,) int, out of bounds = padding
+    is_new: np.ndarray,       # (G,) bool
+    q_ev: np.ndarray,         # (G,) int64
+    q_values: np.ndarray,     # (G, D) f32
     batch_creation_ts: int,
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Returns updated (event_ts, creation_ts, values); inputs untouched."""
     ev = event_ts.copy()
     cr = creation_ts.copy()
     vals = values.copy()
-    p_n, q_n = q_ids.shape
-    for p in range(p_n):
-        for q in range(q_n):
-            k = q_ids[p, q]
-            if k < 0:
-                continue
-            slots = np.flatnonzero(keys[p] == k)
-            for s in slots:
-                if (q_ev[p, q], batch_creation_ts) > (ev[p, s], cr[p, s]):
-                    ev[p, s] = q_ev[p, q]
-                    cr[p, s] = batch_creation_ts
-                    vals[p, s] = q_values[p, q]
+    p_n, c_n = ev.shape
+    for p, s, new, e, row in zip(part, slot, is_new, q_ev, q_values):
+        if not (0 <= p < p_n and 0 <= s < c_n):
+            continue
+        if new or (int(e), batch_creation_ts) > (int(ev[p, s]), int(cr[p, s])):
+            ev[p, s] = e
+            cr[p, s] = batch_creation_ts
+            vals[p, s] = row
     return ev, cr, vals

@@ -3,8 +3,8 @@
 The request path exercises the paper's low-latency plane end to end:
   1. each request names a document/session (entity id);
   2. the ONLINE store serves the session's latest context feature (its most
-     recent token chunk — the "session state" pattern) via the Pallas
-     lookup kernel;
+     recent token chunk — the "session state" pattern) via the device GET
+     (key-index search + row gather);
   3. the model prefills the retrieved context and decodes new tokens.
 
 Offline/online skew shows up here as a wrong prompt — the integration test

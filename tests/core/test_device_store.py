@@ -148,7 +148,7 @@ def test_grow_mid_kernel_stream_identical():
         k.merge(spec, f, 1_000 + lo)
         l.merge(spec, f, 1_000 + lo)
     assert_online_identical(k, l, spec, "grow under kernel engine")
-    assert k._tables[spec.key].keys_lo.shape[1] >= 256
+    assert k._tables[spec.key].keys_full.shape[1] >= 256
 
 
 def test_mirror_is_writable_after_kernel_merge():
@@ -191,7 +191,7 @@ def test_sweep_recycles_slots_capacity_bounded(engine):
             store.sweep("fs", 1, now=now)
         store.merge(spec, f, now + 1)
     t = store._tables[spec.key]
-    assert t.keys_lo.shape[1] == 64, "TTL churn leaked capacity"
+    assert t.keys_full.shape[1] == 64, "TTL churn leaked capacity"
     assert store.num_records("fs", 1) == 80
     # fill is bounded by live records + transient imbalance, never cumulative
     assert int(t.fill.sum()) <= 128
@@ -318,7 +318,7 @@ def test_padded_value_plane_matches_vector_engine(tmp_path, n_feats):
 
     def plane_ok(label):
         vals = np.asarray(k.device_state("fs", 1).values)
-        c = k._tables[spec.key].keys_lo.shape[1]
+        c = k._tables[spec.key].keys_full.shape[1]
         assert vals.shape == (2, c, width), label
         assert not vals[..., n_feats:].any(), f"{label}: pad columns written"
 
@@ -337,7 +337,7 @@ def test_padded_value_plane_matches_vector_engine(tmp_path, n_feats):
     f = make_frame(rng, 60, 400, 200, n_feats=n_feats)
     k.merge(spec, f, 1_100)
     v.merge(spec, f, 1_100)
-    assert k._tables[spec.key].keys_lo.shape[1] > 8
+    assert k._tables[spec.key].keys_full.shape[1] > 8
     plane_ok("after grow")
     ids = np.concatenate([ids, np.unique(f["entity_id"])])
     same_gets(1_110, "after grow")
@@ -349,6 +349,38 @@ def test_padded_value_plane_matches_vector_engine(tmp_path, n_feats):
         k.lookup_encoded("fs", 1, ids[:5])
     (lookup,) = SPANS.spans("fs.store.lookup")
     assert lookup.attrs["pad_cols"] == width - n_feats
+
+
+@pytest.mark.parametrize("n_feats", [1, 5, 9, 57])
+def test_kernel_get_matches_host_get_through_kernel_merges(n_feats):
+    """Kernel merges that insert, override and no-op, interleaved with
+    kernel GETs: every GET triple is byte-identical to a host GET of a
+    vector-engine store fed the same batches, at widths the device plane
+    keeps (1), pads to 8 (5), to 16 (9) and to 128 (57)."""
+    spec = make_spec(ttl=1_000, n_feats=n_feats)
+    k = OnlineStore(num_partitions=4, initial_capacity=16, merge_engine="kernel")
+    v = OnlineStore(num_partitions=4, initial_capacity=16, merge_engine="vector")
+    rng = np.random.default_rng(13 + n_feats)
+    ids = np.arange(-3, 90, dtype=np.int64)
+    for i in range(6):
+        # each batch reaches new ids (inserts) and revisits old ones
+        # (overrides and no-ops); the table doubles on the way, and GETs
+        # at ``now`` see the first cohort expire
+        f = make_frame(rng, 40 if i != 3 else 120, 30 + 12 * i, 20 + 5 * i,
+                       n_feats=n_feats)
+        k.merge(spec, f, 1_000 + 10 * (i % 3))
+        v.merge(spec, f, 1_000 + 10 * (i % 3))
+        assert k._tables[spec.key].host_stale
+        now = 1_000 + 10 * i + 995
+        got_k = k.lookup_encoded("fs", 1, ids, now=now)
+        got_v = v.lookup_encoded("fs", 1, ids, now=now, use_kernel=False)
+        for a, b in zip(got_k, got_v):
+            assert a.dtype == b.dtype and a.shape == b.shape, i
+            assert a.tobytes() == b.tobytes(), i
+    assert k._tables[spec.key].keys_full.shape[1] > 16
+    assert k.inserts == v.inserts and k.overrides and k.noops
+    assert (k.overrides, k.noops) == (v.overrides, v.noops)
+    assert_online_identical(k, v, spec, f"width {n_feats}")
 
 
 # -- the device key index the kernel GET searches -----------------------------
@@ -397,7 +429,7 @@ def test_device_index_exact_through_merges_sweep_and_grow():
             same_gets(now, f"{label} @ {now}")
         updates.append(store.transfers["index_updates"])
     assert store.num_records("fs", 1) > 0 and store.overrides and store.noops
-    assert store._tables[spec.key].keys_lo.shape[1] > 64
+    assert store._tables[spec.key].keys_full.shape[1] > 64
     # the inserts after the load were merged into the device index in place
     assert updates[1] == 1 and updates[-1] > updates[1]
 

@@ -214,7 +214,7 @@ def test_online_growth_boundary_identical():
         store.merge(spec, frame, 100)
     assert_online_identical(stores["loop"], stores["vector"], spec, "grow/vector")
     assert_online_identical(stores["loop"], stores["kernel"], spec, "grow/kernel")
-    assert stores["loop"]._tables[spec.key].keys_lo.shape[1] >= 256
+    assert stores["loop"]._tables[spec.key].keys_full.shape[1] >= 256
 
 
 def test_online_ttl_sweep_interleaved_identical():

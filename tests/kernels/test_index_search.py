@@ -14,6 +14,7 @@ from hypothesis import given, settings, strategies as st
 from repro.kernels.online_lookup.ops import (
     lookup,
     merge_index,
+    partition_of,
     pow2_bucket,
     split_i64,
 )
@@ -21,6 +22,27 @@ from repro.kernels.online_lookup.ops import (
 I64_MAX = np.iinfo(np.int64).max
 I64_MIN = np.iinfo(np.int64).min
 PAD = np.array([-1, np.iinfo(np.int32).max, -1, -1], np.int32)
+
+
+def test_split_i64_roundtrip():
+    ids = np.array([0, 1, 2**31, 2**40 + 17, -5, np.iinfo(np.int64).max], np.int64)
+    lo, hi = split_i64(ids)
+    rebuilt = (
+        lo.view(np.uint32).astype(np.uint64)
+        | (hi.view(np.uint32).astype(np.uint64) << np.uint64(32))
+    ).view(np.int64)
+    np.testing.assert_array_equal(rebuilt, ids)
+
+
+def test_partition_routing_stable_and_in_range():
+    ids = np.arange(1, 5000, dtype=np.int64)
+    p1 = partition_of(ids, 16)
+    p2 = partition_of(ids, 16)
+    np.testing.assert_array_equal(p1, p2)
+    assert p1.min() >= 0 and p1.max() < 16
+    # reasonable balance for the Fibonacci mix: no partition > 3x the mean
+    counts = np.bincount(p1, minlength=16)
+    assert counts.max() < 3 * counts.mean()
 
 
 def index_planes(keys, n, seed=0):
@@ -116,6 +138,24 @@ def test_lookup_hits_and_misses_across_buckets(batch):
     )
     rng.shuffle(ids)
     assert_search(keys, ids, 3 * 1024 + 5, seed=batch)
+
+
+@pytest.mark.parametrize(
+    ("batch", "bucket"), [(1, 128), (127, 128), (128, 128), (129, 256), (4096, 4096)]
+)
+def test_lookup_at_bucket_edges(batch, bucket):
+    """Batches on each side of the first ``pow2_bucket`` edge, and the
+    serving front's largest: the pad queries (key 0, stored here) never
+    leak into the answers the store keeps, the first ``batch``."""
+    assert pow2_bucket(batch) == bucket
+    rng = np.random.default_rng(batch + 7)
+    keys = np.unique(np.concatenate([[0], rng.integers(-(1 << 62), 1 << 62, 5_000)]))
+    ids = np.where(
+        rng.random(batch) < 0.5,
+        rng.choice(keys, batch),
+        rng.integers(-(1 << 62), 1 << 62, batch),
+    )
+    assert_search(keys, ids, 8 * 1024, seed=batch)
 
 
 @settings(max_examples=25, deadline=None)

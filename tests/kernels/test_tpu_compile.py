@@ -28,9 +28,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.online_store import device_width
-from repro.kernels.online_lookup.kernel import lookup_kernel_call
 from repro.kernels.online_lookup.ops import gather_rows, lookup, merge_index
-from repro.kernels.online_merge.kernel import merge_kernel_call
 from repro.kernels.online_merge.ops import merge_at_slots
 from repro.kernels.pit_join.kernel import pit_search_kernel_call
 from repro.kernels.rolling_agg.kernel import rolling_sum_kernel_call
@@ -38,7 +36,6 @@ from repro.kernels.rolling_agg.ops import max_hist, rolling_sum_xla
 
 PARTS, SLOTS, FEATS = 16, 1 << 16, 8  # online table after growth to ~2^20 ids
 MERGE_BATCH = 1 << 21  # one 2-hour tick of 2^20 events per hour
-GET_QUERIES = 512  # per-partition bucket of a 4096-id GET
 ROLL_ROWS = 6 << 20  # the 6-hour transform window
 HISTORY_ROWS = 6 << 20
 SPINE = 65_536
@@ -94,30 +91,6 @@ def _check(compiled, *, kernel: bool, plane_bytes: int = 0):
         )
 
 
-def test_online_lookup_compiles(one_chip):
-    plane = _spec(one_chip, (PARTS, SLOTS))
-    query = _spec(one_chip, (PARTS, GET_QUERIES, 1))
-    compiled = lookup_kernel_call.lower(
-        plane, plane, query, query, slot_block=1024, q_block=256, interpret=False
-    ).compile()
-    _check(compiled, kernel=True)
-
-
-def test_online_merge_compiles(one_chip):
-    q = MERGE_BATCH // PARTS
-    plane = _spec(one_chip, (PARTS, SLOTS))
-    query = _spec(one_chip, (PARTS, q, 1))
-    compiled = merge_kernel_call.lower(
-        plane, plane, plane, plane, plane, plane,
-        _spec(one_chip, (PARTS, FEATS, SLOTS), jnp.float32),
-        query, query, query, query,
-        _spec(one_chip, (PARTS, FEATS, q), jnp.float32),
-        _spec(one_chip, (2,)),
-        slot_block=512, q_block=128, interpret=False,
-    ).compile()
-    _check(compiled, kernel=True)
-
-
 def test_pit_join_compiles(one_chip):
     query = _spec(one_chip, (SPINE, 1))
     compiled = pit_search_kernel_call.lower(
@@ -156,15 +129,18 @@ def test_merge_at_slots_compiles(one_chip):
     plane = _spec(one_chip, (PARTS, SLOTS))
     batch = _spec(one_chip, (MERGE_BATCH,))
     compiled = merge_at_slots.lower(
-        plane, plane, plane, plane, plane, plane,
+        plane, plane, plane, plane,
         _spec(one_chip, (PARTS, SLOTS, FEATS), jnp.float32),
-        batch, batch, batch, batch,
+        batch, batch,
         _spec(one_chip, (MERGE_BATCH,), jnp.bool_),
         batch, batch,
         _spec(one_chip, (2,)),
         _spec(one_chip, (MERGE_BATCH, FEATS), jnp.float32),
     ).compile()
     _check(compiled, kernel=False)
+    # the five table planes are donated, and rewritten in place
+    plane_bytes = 4 * PARTS * SLOTS * 4 + PARTS * SLOTS * FEATS * 4
+    assert compiled.memory_analysis().alias_size_in_bytes == plane_bytes
 
 
 def test_gather_rows_compiles(one_chip):
@@ -201,8 +177,8 @@ def test_value_plane_programs_copy_free(one_chip, program, features):
     else:
         batch = _spec(one_chip, (UPDATE_BATCH,))
         lowered = merge_at_slots.lower(
-            plane, plane, plane, plane, plane, plane, values,
-            batch, batch, batch, batch,
+            plane, plane, plane, plane, values,
+            batch, batch,
             _spec(one_chip, (UPDATE_BATCH,), jnp.bool_),
             batch, batch,
             _spec(one_chip, (2,)),
@@ -218,9 +194,9 @@ def test_index_search_compiles_without_table_work(one_chip, batch):
     """The GET's key search over the benchmark's index (16 x 2^18 entries)
     and the gather it feeds.  The search does O(B log N) operations, at
     most 64 a query and step, and holds no temporary: one that compared
-    every entry (``searchsorted(method="compare_all")``, the slot scan)
-    does B x N, and any pass over the index at least N, more than the
-    bound at the small batches.  The TPU cost model charges each gather its
+    every entry (``searchsorted(method="compare_all")``) does B x N, and
+    any pass over the index at least N, more than the bound at the small
+    batches.  The TPU cost model charges each gather its
     whole operand as bytes accessed, so bytes cannot tell these apart."""
     n = PARTS * BENCH_SLOTS
     index = _spec(one_chip, (n,))
